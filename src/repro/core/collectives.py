@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.conduit import axis_size
 from repro.core.modes import AsyncMode
 
 POD_AXIS = "pod"
@@ -77,7 +76,7 @@ def exchange_gradients(grads, state: dict, mode: AsyncMode,
     reduction issued here is consumed next step, so the scheduler overlaps it
     with the whole of this step's compute.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if mode == AsyncMode.BARRIER_EVERY_STEP:
         return jax.tree.map(lambda g: g / n, lax.psum(grads, axis_name)), state
     if mode in (AsyncMode.ROLLING_BARRIER, AsyncMode.FIXED_BARRIER,
@@ -100,7 +99,7 @@ def exchange_gradients(grads, state: dict, mode: AsyncMode,
 # Periodic parameter sync (modes 1/2 outer step)
 # ---------------------------------------------------------------------------
 def pod_mean(tree, axis_name: str = POD_AXIS):
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     return jax.tree.map(lambda x: lax.psum(x, axis_name) / n, tree)
 
 

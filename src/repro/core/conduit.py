@@ -24,18 +24,10 @@ def ring_perm(n: int, shift: int):
     return [(i, (i + shift) % n) for i in range(n)]
 
 
-def axis_size(axis_name: str):
-    """Version-compat ``lax.axis_size`` (older jax: the psum-of-1 idiom,
-    which constant-folds to the axis size at trace time)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def ring_exchange(x, axis_name: str, shift: int = 1):
     """Rotate ``x`` around the ring: device i receives device (i - shift)'s
     value (i.e. values travel ``shift`` steps forward)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     return lax.ppermute(x, axis_name, ring_perm(n, shift))
 
 

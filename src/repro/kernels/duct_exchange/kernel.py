@@ -1,14 +1,19 @@
-"""Fused best-effort duct exchange — Pallas TPU kernel.
+"""Duct-ring Pallas TPU kernels.
 
-One lockstep window of duct traffic for a block of directed edges: the
-send-attempt → capacity-drop → latency-stamp → drain pass fused into a
-single VMEM-resident sweep.  Unlike the CPU jnp twin (which unrolls
-``max_pops`` gather/scatter rounds), the kernel is gather-free: FIFO
-offsets are recovered from a broadcasted lane iota, the drained prefix is
-found with a row-min over blocked offsets, and pops/pushes are applied as
-masked writes over the whole (block, capacity) tile — VPU-shaped work.
+``duct_window_kernel`` runs one window of the dense layout (apply the
+staged sends, then drain) and ``duct_commit_kernel`` folds a W-fused
+superstep's pushbuf into the rings; both are the engine's main path on
+TPU.  Their ring state is laid out slot-major, ``(C, rings)``: rings lie
+on the 128 lanes and slots on sublanes, so per-ring scalars are lane rows
+that broadcast down the slots, the drained prefix is a sublane min over
+blocked FIFO offsets, and every mask is an int32 compare against a slot
+iota at full rank — no gathers, no boolean reshapes.  Payload words are
+separate slot-major planes, ``(L, C, rings)``.  The grid is 1-D over lane
+blocks of rings sized to a VMEM budget, and a ragged last block needs no
+padding because no ring reads another.
 
-Grid is 1-D over edge blocks; each edge's ring is one tile row.
+``duct_exchange_kernel`` is the edge-major drain -> send pass with one ring
+per tile row; no engine calls it.
 """
 from __future__ import annotations
 
@@ -23,9 +28,10 @@ from repro.kernels.duct_exchange.ops import dense_halo_select
 
 _BLOCK_EDGES = 256
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+#: double-buffered VMEM bytes one grid step's blocks may take; v5e's
+#: default scoped VMEM limit is 16 MiB, and the kernels' intermediates
+#: need the rest
+_VMEM_BUDGET = 4 * 1024 * 1024
 
 
 def _duct_kernel(qa_ref, qt_ref, head_ref, size_ref,
@@ -78,61 +84,85 @@ def _duct_kernel(qa_ref, qt_ref, head_ref, size_ref,
     push_pos_out[...] = push_pos
 
 
+def _ring_block(R: int, bytes_per_ring: int) -> int:
+    """Lane-block width over rings: the whole ring axis when it fits the
+    VMEM budget, else the widest multiple of 128 lanes that does."""
+    fit = max(128, _VMEM_BUDGET // max(bytes_per_ring, 1) // 128 * 128)
+    return R if R <= fit else fit
+
+
+def _row_bytes(*rows_and_bytes) -> int:
+    """Double-buffered VMEM bytes per ring of a kernel's blocks: each
+    ``(rows, itemsize)`` pair is one block, its sublane rows padded to 8."""
+    return 2 * sum(-(-r // 8) * 8 * b for r, b in rows_and_bytes)
+
+
 def _window_kernel(qa_ref, qt_ref, qp_ref, head_ref, size_ref,
                    ppos_ref, pacc_ref, pav_ref, ptch_ref, ppay_ref,
                    rnow_ref, ract_ref,
                    qa_out, qt_out, qp_out, head_out, size_out,
-                   drained_out, rtouch_out, hpay_out, hwin_out,
+                   drained_out, rtouch_out, fpay_out,
                    *, max_pops: int):
-    """Fused dense-layout window: push-apply -> drain -> halo-select, one
-    VMEM-resident sweep over a block of receivers' (d, C) ring tiles.
+    """Fused dense-layout window over a block of rings laid out on lanes:
+    push-apply -> drain, one VMEM-resident read-modify-write sweep.
 
+    Ring state is slot-major, ``(C, rings)``, so every per-ring scalar is
+    a ``(1, rings)`` row that broadcasts down the slot sublanes and every
+    mask is built at full rank from int32 compares against a slot iota.
     The push phase only applies sends the engine already accepted (the
     drop-iff-full decision and occupancy bump happened eagerly at stage
-    time), so the whole window's ring-state HBM traffic is this single
-    read-modify-write pass.
+    time), so the whole window's ring-state HBM traffic is this pass.
     """
-    qa = qa_ref[...]                 # (B, d, C) availability times
-    qt = qt_ref[...]                 # (B, d, C) touch stamps
-    qp = qp_ref[...]                 # (B, d, C, L) payloads
-    head = head_ref[...]             # (B, d)
-    size = size_ref[...]             # (B, d) — staged pushes already counted
-    ppos, pacc = ppos_ref[...], pacc_ref[...]
-    pav, ptch, ppay = pav_ref[...], ptch_ref[...], ppay_ref[...]
-    rnow, ract = rnow_ref[...], ract_ref[...]   # (B, 1)
-    B, d, C = qa.shape
+    qa = qa_ref[...]                 # (C, B) availability times
+    qt = qt_ref[...]                 # (C, B) touch stamps
+    head = head_ref[...]             # (1, B)
+    size = size_ref[...]             # (1, B) — staged pushes already counted
+    rnow, ract = rnow_ref[...], ract_ref[...]
+    C = qa.shape[0]
+    L = qp_ref.shape[0]
 
-    col = jax.lax.broadcasted_iota(jnp.int32, (B, d, C), dimension=2)
+    slot = jax.lax.broadcasted_iota(jnp.int32, qa.shape, 0)
     # --- push: masked writes at the staged slots --------------------------
-    at = (pacc > 0)[:, :, None] & (col == ppos[:, :, None])
-    qa = jnp.where(at, pav[:, :, None], qa)
-    qt = jnp.where(at, ptch[:, :, None], qt)
-    qp = jnp.where(at[..., None], ppay[:, :, None, :], qp)
+    at = slot == jnp.where(pacc_ref[...] > 0, ppos_ref[...], -1)
+    qa = jnp.where(at, pav_ref[...], qa)
+    qt = jnp.where(at, ptch_ref[...], qt)
     # --- drain: longest available FIFO prefix, head-blocking, bounded -----
-    off = (col - head[:, :, None]) % C
-    valid = off < size[:, :, None]
-    blocked = valid & (qa > rnow[:, :, None])
-    blocked_off = jnp.min(jnp.where(blocked, off, C), axis=2)
+    off = (slot - head) % C
+    valid = off < size
+    blocked = valid & (qa > rnow)
+    blocked_off = jnp.min(jnp.where(blocked, off, C), axis=0, keepdims=True)
     dr = jnp.minimum(jnp.minimum(blocked_off, size), max_pops)
     dr = jnp.where(ract > 0, dr, 0)
-    popped = valid & (off < dr[:, :, None])
-    fresh = popped & (off == dr[:, :, None] - 1)
-    rtouch = jnp.sum(jnp.where(fresh, qt, 0), axis=2)
-    fpay = jnp.sum(jnp.where(fresh[..., None], qp,
-                             jnp.zeros((), qp.dtype)), axis=2)  # (B, d, L)
-    qa = jnp.where(popped, jnp.inf, qa)
-    # --- halo select: the shared ascending-j unrolled select --------------
-    hpay, hwin = dense_halo_select(dr > 0, fpay)
-
-    qa_out[...] = qa
+    popped = valid & (off < dr)
+    fresh = valid & (off == dr - 1)
+    rtouch_out[...] = jnp.sum(jnp.where(fresh, qt, 0), axis=0, keepdims=True)
+    for l in range(L):
+        qp = jnp.where(at, ppay_ref[l:l + 1, :], qp_ref[l])
+        qp_out[l] = qp
+        fpay_out[l:l + 1, :] = jnp.sum(
+            jnp.where(fresh, qp, jnp.zeros((), qp.dtype)),
+            axis=0, keepdims=True)
+    qa_out[...] = jnp.where(popped, jnp.inf, qa)
     qt_out[...] = qt
-    qp_out[...] = qp
     head_out[...] = (head + dr) % C
     size_out[...] = size - dr
     drained_out[...] = dr
-    rtouch_out[...] = rtouch
-    hpay_out[...] = hpay
-    hwin_out[...] = hwin.astype(jnp.int32)
+
+
+def _lanes(x, dtype, R):
+    """A per-ring array as one ``(1, R)`` lane row."""
+    return jnp.asarray(x, dtype).reshape(1, R)
+
+
+def _slot_major(x, dtype, R, C):
+    """``(..., C)`` ring state as slot-major ``(C, R)``."""
+    return jnp.asarray(x, dtype).reshape(R, C).T
+
+
+def _pay_major(x, R, C):
+    """``(..., C, L)`` payloads as ``(L, C, R)``: one slot-major plane per
+    payload word."""
+    return jnp.transpose(x.reshape(R, C, x.shape[-1]), (2, 1, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("max_pops", "interpret"))
@@ -140,88 +170,91 @@ def duct_window_kernel(q_avail, q_touch, q_pay, head, size,
                        push_pos, push_acc, push_avail, push_touch, push_pay,
                        recv_now, recv_active,
                        *, max_pops: int, interpret: bool = False):
-    """Fused window megakernel over all receivers.  Returns the same tuple
-    layout as ``ops.WindowResult`` (halo_win as bool)."""
+    """Fused window over all rings, then the per-receiver halo merge.
+    Returns the same tuple layout as ``ops.WindowResult`` (halo_win as
+    bool).
+
+    The kernel is per-ring and gather-free; the halo merge, which combines
+    the ``d`` rings of a receiver, runs on the kernel's narrow ``(n, d,
+    L)`` freshest-payload output with the shared ``dense_halo_select``.
+    """
     n, d, C = q_avail.shape
     L = q_pay.shape[-1]
-    B = max(1, min(_BLOCK_EDGES // max(d, 1), n))
-    pad = (-n) % B
-    nb = (n + pad) // B
+    R = n * d
+    pdt = q_pay.dtype
+    isz = jnp.dtype(pdt).itemsize
+    B = _ring_block(R, _row_bytes((C, 4), (C, 4), *[(C, isz)] * L,
+                                  *[(1, 4)] * 10, (L, isz)) * 2)
+    rcv = jnp.arange(R, dtype=jnp.int32) // d
+    args = (_slot_major(q_avail, jnp.float32, R, C),
+            _slot_major(q_touch, jnp.int32, R, C),
+            _pay_major(q_pay, R, C),
+            _lanes(head, jnp.int32, R), _lanes(size, jnp.int32, R),
+            _lanes(push_pos, jnp.int32, R), _lanes(push_acc, jnp.int32, R),
+            _lanes(push_avail, jnp.float32, R),
+            _lanes(push_touch, jnp.int32, R),
+            jnp.asarray(push_pay, pdt).reshape(R, L).T,
+            # receiver -> ring broadcast as a gather: the interleaving
+            # repeat lowers to an unrolled relayout copy on TPU
+            _lanes(jnp.asarray(recv_now)[rcv], jnp.float32, R),
+            _lanes(jnp.asarray(recv_active)[rcv], jnp.int32, R))
 
-    def prep(x, dtype, tail=()):
-        x = jnp.asarray(x, dtype).reshape((n,) + tail)
-        return jnp.pad(x, ((0, pad),) + ((0, 0),) * len(tail))
-
-    args = (prep(q_avail, jnp.float32, (d, C)),
-            prep(q_touch, jnp.int32, (d, C)),
-            prep(q_pay, q_pay.dtype, (d, C, L)),
-            prep(head, jnp.int32, (d,)), prep(size, jnp.int32, (d,)),
-            prep(push_pos, jnp.int32, (d,)),
-            prep(push_acc, jnp.int32, (d,)),
-            prep(push_avail, jnp.float32, (d,)),
-            prep(push_touch, jnp.int32, (d,)),
-            prep(push_pay, q_pay.dtype, (d, L)),
-            prep(recv_now, jnp.float32, (1,)),
-            prep(recv_active, jnp.int32, (1,)))
-
-    spec = lambda *tail: pl.BlockSpec((B,) + tail,  # noqa: E731
-                                      lambda i: (i,) + (0,) * len(tail))
-    out = pl.pallas_call(
+    ring = pl.BlockSpec((C, B), lambda i: (0, i))
+    row = pl.BlockSpec((1, B), lambda i: (0, i))
+    pay = pl.BlockSpec((L, C, B), lambda i: (0, 0, i))
+    words = pl.BlockSpec((L, B), lambda i: (0, i))
+    vec = jax.ShapeDtypeStruct((1, R), jnp.int32)
+    qa2, qt2, qp2, head2, size2, drained, rtouch, fpay = pl.pallas_call(
         functools.partial(_window_kernel, max_pops=max_pops),
-        grid=(nb,),
-        in_specs=[spec(d, C), spec(d, C), spec(d, C, L), spec(d), spec(d),
-                  spec(d), spec(d), spec(d), spec(d), spec(d, L),
-                  spec(1), spec(1)],
-        out_specs=[spec(d, C), spec(d, C), spec(d, C, L), spec(d), spec(d),
-                   spec(d), spec(d), spec(4, L), spec(4)],
-        out_shape=[
-            jax.ShapeDtypeStruct((n + pad, d, C), jnp.float32),
-            jax.ShapeDtypeStruct((n + pad, d, C), jnp.int32),
-            jax.ShapeDtypeStruct((n + pad, d, C, L), q_pay.dtype),
-            jax.ShapeDtypeStruct((n + pad, d), jnp.int32),
-            jax.ShapeDtypeStruct((n + pad, d), jnp.int32),
-            jax.ShapeDtypeStruct((n + pad, d), jnp.int32),
-            jax.ShapeDtypeStruct((n + pad, d), jnp.int32),
-            jax.ShapeDtypeStruct((n + pad, 4, L), q_pay.dtype),
-            jax.ShapeDtypeStruct((n + pad, 4), jnp.int32),
-        ],
-        compiler_params=_CompilerParams(
+        grid=(pl.cdiv(R, B),),
+        in_specs=[ring, ring, pay] + [row] * 6 + [words, row, row],
+        out_specs=[ring, ring, pay, row, row, row, row, words],
+        out_shape=[jax.ShapeDtypeStruct((C, R), jnp.float32),
+                   jax.ShapeDtypeStruct((C, R), jnp.int32),
+                   jax.ShapeDtypeStruct((L, C, R), pdt),
+                   vec, vec, vec, vec,
+                   jax.ShapeDtypeStruct((L, R), pdt)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)
-    qa2, qt2, qp2, head2, size2, drained, rtouch, hpay, hwin = out
-    return (qa2[:n], qt2[:n], qp2[:n], head2[:n], size2[:n], drained[:n],
-            rtouch[:n], hpay[:n], hwin[:n].astype(bool))
+    drained = drained.reshape(n, d)
+    hpay, hwin = dense_halo_select(drained > 0, fpay.T.reshape(n, d, L))
+    return (qa2.T.reshape(n, d, C), qt2.T.reshape(n, d, C),
+            jnp.transpose(qp2, (2, 1, 0)).reshape(n, d, C, L),
+            head2.reshape(n, d), size2.reshape(n, d), drained,
+            rtouch.reshape(n, d), hpay, hwin)
 
 
 def _commit_kernel(qa_ref, qt_ref, qp_ref, head_ref, size0_ref, cnt_ref,
                    pav_ref, ptch_ref, ppay_ref,
                    qa_out, qt_out, qp_out):
     """Superstep commit: fold each ring's compact pushbuf (up to W staged
-    pushes) into the base ring at the live-tail slots.  Gather-free: every
-    ring slot recovers its pushbuf index from a column iota and the write
-    is an ascending-j unrolled masked select — dead (j >= cnt) slots keep
-    their base values.
+    pushes) into the base ring at the live-tail slots.  Gather-free: rings
+    lie on lanes, and each push ``j`` is one masked select down the slot
+    sublanes — dead (j >= cnt) pushes match no slot and leave the base
+    values.
     """
-    qa = qa_ref[...]                 # (B, C)
-    qt = qt_ref[...]                 # (B, C)
-    qp = qp_ref[...]                 # (B, C, L)
-    head = head_ref[...]             # (B, 1)
-    size0 = size0_ref[...]           # (B, 1)
-    cnt = cnt_ref[...]               # (B, 1)
-    pav, ptch, ppay = pav_ref[...], ptch_ref[...], ppay_ref[...]
-    B, C = qa.shape
-    W = pav.shape[1]
+    qa = qa_ref[...]                 # (C, B)
+    qt = qt_ref[...]                 # (C, B)
+    tail = head_ref[...] + size0_ref[...]   # (1, B)
+    cnt = cnt_ref[...]               # (1, B)
+    C = qa.shape[0]
+    W = pav_ref.shape[0]
+    L = qp_ref.shape[0]
 
-    col = jax.lax.broadcasted_iota(jnp.int32, (B, C), dimension=1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, qa.shape, 0)
+    at = [slot == jnp.where(cnt > j, (tail + j) % C, -1) for j in range(W)]
     for j in range(W):
-        at = (col == (head + size0 + j) % C) & (j < cnt)
-        qa = jnp.where(at, pav[:, j:j + 1], qa)
-        qt = jnp.where(at, ptch[:, j:j + 1], qt)
-        qp = jnp.where(at[..., None], ppay[:, j:j + 1, :], qp)
+        qa = jnp.where(at[j], pav_ref[j:j + 1, :], qa)
+        qt = jnp.where(at[j], ptch_ref[j:j + 1, :], qt)
     qa_out[...] = qa
     qt_out[...] = qt
-    qp_out[...] = qp
+    for l in range(L):
+        qp = qp_ref[l]
+        for j in range(W):
+            qp = jnp.where(at[j], ppay_ref[l, j:j + 1, :], qp)
+        qp_out[l] = qp
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -233,42 +266,39 @@ def duct_commit_kernel(q_avail, q_touch, q_pay, head, size0, pb_cnt,
     R, C = q_avail.shape
     W = pb_avail.shape[1]
     L = q_pay.shape[-1]
-    B = min(_BLOCK_EDGES, R)
-    pad = (-R) % B
-    nb = (R + pad) // B
+    pdt = q_pay.dtype
+    isz = jnp.dtype(pdt).itemsize
+    B = _ring_block(R, _row_bytes((C, 4), (C, 4), *[(C, isz)] * L,
+                                  *[(1, 4)] * 3, (W, 4), (W, 4),
+                                  *[(W, isz)] * L)
+                    + _row_bytes((C, 4), (C, 4), *[(C, isz)] * L))
+    args = (_slot_major(q_avail, jnp.float32, R, C),
+            _slot_major(q_touch, jnp.int32, R, C),
+            _pay_major(q_pay, R, C),
+            _lanes(head, jnp.int32, R), _lanes(size0, jnp.int32, R),
+            _lanes(pb_cnt, jnp.int32, R),
+            jnp.asarray(pb_avail, jnp.float32).T,
+            jnp.asarray(pb_touch, jnp.int32).T,
+            jnp.transpose(jnp.asarray(pb_pay, pdt), (2, 1, 0)))
 
-    def prep(x, dtype, tail=()):
-        x = jnp.asarray(x, dtype).reshape((R,) + tail)
-        return jnp.pad(x, ((0, pad),) + ((0, 0),) * len(tail))
-
-    args = (prep(q_avail, jnp.float32, (C,)),
-            prep(q_touch, jnp.int32, (C,)),
-            prep(q_pay, q_pay.dtype, (C, L)),
-            prep(head, jnp.int32, (1,)), prep(size0, jnp.int32, (1,)),
-            prep(pb_cnt, jnp.int32, (1,)),
-            prep(pb_avail, jnp.float32, (W,)),
-            prep(pb_touch, jnp.int32, (W,)),
-            prep(pb_pay, q_pay.dtype, (W, L)))
-
-    spec = lambda *tail: pl.BlockSpec((B,) + tail,  # noqa: E731
-                                      lambda i: (i,) + (0,) * len(tail))
-    out = pl.pallas_call(
+    ring = pl.BlockSpec((C, B), lambda i: (0, i))
+    row = pl.BlockSpec((1, B), lambda i: (0, i))
+    pay = pl.BlockSpec((L, C, B), lambda i: (0, 0, i))
+    pushes = pl.BlockSpec((W, B), lambda i: (0, i))
+    qa2, qt2, qp2 = pl.pallas_call(
         _commit_kernel,
-        grid=(nb,),
-        in_specs=[spec(C), spec(C), spec(C, L), spec(1), spec(1), spec(1),
-                  spec(W), spec(W), spec(W, L)],
-        out_specs=[spec(C), spec(C), spec(C, L)],
-        out_shape=[
-            jax.ShapeDtypeStruct((R + pad, C), jnp.float32),
-            jax.ShapeDtypeStruct((R + pad, C), jnp.int32),
-            jax.ShapeDtypeStruct((R + pad, C, L), q_pay.dtype),
-        ],
-        compiler_params=_CompilerParams(
+        grid=(pl.cdiv(R, B),),
+        in_specs=[ring, ring, pay, row, row, row, pushes, pushes,
+                  pl.BlockSpec((L, W, B), lambda i: (0, 0, i))],
+        out_specs=[ring, ring, pay],
+        out_shape=[jax.ShapeDtypeStruct((C, R), jnp.float32),
+                   jax.ShapeDtypeStruct((C, R), jnp.int32),
+                   jax.ShapeDtypeStruct((L, C, R), pdt)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)
-    qa2, qt2, qp2 = out
-    return qa2[:R], qt2[:R], qp2[:R]
+    return qa2.T, qt2.T, jnp.transpose(qp2, (2, 1, 0))
 
 
 @functools.partial(jax.jit,
@@ -309,7 +339,7 @@ def duct_exchange_kernel(q_avail, q_touch, head, size,
             jax.ShapeDtypeStruct((E + pad, C), jnp.float32),
             jax.ShapeDtypeStruct((E + pad, C), jnp.int32),
         ] + [jax.ShapeDtypeStruct((E + pad, 1), jnp.int32)] * 7,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)

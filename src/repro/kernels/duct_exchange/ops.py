@@ -111,12 +111,6 @@ def duct_exchange_jnp(q_avail, q_touch, head, size,
                           d.recv_touch, d.pop_pos, s.accepted, s.push_pos)
 
 
-def _auto_interpret(interpret):
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
-
-
 # ---------------------------------------------------------------------------
 # Fused dense-layout window megakernel (DESIGN.md §10)
 # ---------------------------------------------------------------------------
@@ -228,9 +222,11 @@ def duct_window(q_avail, q_touch, q_pay, head, size,
                 recv_now, recv_active,
                 *, max_pops: int,
                 use_pallas: bool = None,
-                interpret=None) -> WindowResult:
-    """Backend dispatch for the fused window op: Pallas megakernel on TPU
-    (one VMEM-resident sweep per receiver block), jnp twin elsewhere."""
+                interpret: bool = False) -> WindowResult:
+    """Backend dispatch for the fused window op: Pallas kernel on TPU (one
+    VMEM-resident sweep per block of rings), jnp twin elsewhere.
+    ``interpret=True`` runs the kernel in the Pallas interpreter (CPU
+    parity tests); nothing else reaches the interpreter."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if not use_pallas:
@@ -243,7 +239,7 @@ def duct_window(q_avail, q_touch, q_pay, head, size,
         q_avail, q_touch, q_pay, head, size,
         push_pos, push_acc, push_avail, push_touch, push_pay,
         recv_now, recv_active, max_pops=max_pops,
-        interpret=_auto_interpret(interpret)))
+        interpret=interpret))
 
 
 class CommitResult(NamedTuple):
@@ -289,10 +285,11 @@ def duct_commit_jnp(q_avail, q_touch, q_pay, head, size0, pb_cnt,
 def duct_commit(q_avail, q_touch, q_pay, head, size0, pb_cnt,
                 pb_avail, pb_touch, pb_pay,
                 *, use_pallas: bool = None,
-                interpret=None) -> CommitResult:
+                interpret: bool = False) -> CommitResult:
     """Backend dispatch for the superstep commit: Pallas kernel on TPU
     (one masked-select sweep per ring block, gather-free), jnp twin
-    elsewhere.  Slot-exact with ``ref.duct_commit_ref``."""
+    elsewhere.  Slot-exact with ``ref.duct_commit_ref``; ``interpret`` as
+    in :func:`duct_window`."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if not use_pallas:
@@ -301,7 +298,7 @@ def duct_commit(q_avail, q_touch, q_pay, head, size0, pb_cnt,
     from repro.kernels.duct_exchange.kernel import duct_commit_kernel
     return CommitResult(*duct_commit_kernel(
         q_avail, q_touch, q_pay, head, size0, pb_cnt,
-        pb_avail, pb_touch, pb_pay, interpret=_auto_interpret(interpret)))
+        pb_avail, pb_touch, pb_pay, interpret=interpret))
 
 
 def duct_exchange(q_avail, q_touch, head, size,
@@ -309,7 +306,7 @@ def duct_exchange(q_avail, q_touch, head, size,
                   send_now, send_active, send_lat, send_touch,
                   *, capacity: int, max_pops: int,
                   use_pallas: bool = None,
-                  interpret=None) -> ExchangeResult:
+                  interpret: bool = False) -> ExchangeResult:
     """Backend dispatch: Pallas kernel on TPU, jnp twin elsewhere.
 
     ``use_pallas=True`` forces the kernel (with ``interpret`` controlling
@@ -327,4 +324,4 @@ def duct_exchange(q_avail, q_touch, head, size,
         q_avail, q_touch, head, size, recv_now, recv_active,
         send_now, send_active, send_lat, send_touch,
         capacity=capacity, max_pops=max_pops,
-        interpret=_auto_interpret(interpret)))
+        interpret=interpret))

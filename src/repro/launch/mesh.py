@@ -10,19 +10,28 @@ device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.models.partitioning import MeshRules
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis typed ``Auto``: sharding stays a
+    compiler decision steered by ``with_sharding_constraint``, which only
+    names Auto axes (``make_mesh`` otherwise defaults to Explicit)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for multi-device CPU tests."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 #: Mesh axis the sharded simulation engine partitions the population over.
@@ -32,9 +41,9 @@ SHARD_AXIS = "shard"
 def make_shard_mesh(n_shards: int):
     """1-D mesh over ``SHARD_AXIS`` for the sharded vectorized engine.
 
-    CI forces host-platform devices via
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so this path is
-    exercised continuously without accelerators (DESIGN.md §8).
+    On CPU, ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` gives
+    the host enough devices to exercise this path without accelerators
+    (DESIGN.md §8).
     """
     n_dev = len(jax.devices())
     if n_shards > n_dev:
@@ -43,39 +52,7 @@ def make_shard_mesh(n_shards: int):
             "are visible; on CPU set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n_shards} before "
             "importing jax")
-    return jax.make_mesh((n_shards,), (SHARD_AXIS,))
-
-
-def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
-    """Version-compat ``shard_map``: top-level ``jax.shard_map`` on current
-    jax, ``jax.experimental.shard_map`` on older releases.  Replication
-    checking is disabled either way — the sharded engine's bodies mix
-    per-shard state with cross-shard collectives, which the static checker
-    over-rejects.
-
-    ``axis_names`` restricts manual axes (partial-auto sharding): passed
-    through on current jax, translated to the legacy ``auto=`` complement
-    on older releases.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if axis_names is None else {"axis_names": axis_names}
-        # the check flag was renamed check_rep -> check_vma across jax
-        # releases; keep checking OFF whichever spelling this jax takes
-        for check_kw in ({"check_vma": False}, {"check_rep": False}, {}):
-            try:
-                return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs, **check_kw,
-                                     **kwargs)
-            except TypeError:
-                continue
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kwargs = {}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - set(axis_names)
-        if auto:
-            kwargs["auto"] = auto
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False, **kwargs)
+    return _auto_mesh((n_shards,), (SHARD_AXIS,))
 
 
 def rules_for(mesh, *, long_context: bool = False,

@@ -105,19 +105,22 @@ class JaxEngine:
         # --- static edge plumbing (numpy, hoisted out of the scan) --------
         esrc, edst, index = canonical_edges(topo)
         slot_maps = [halo_slot_map(topo.neighbors[p]) for p in range(n)]
-        slot = [slot_maps[d][s] for s, d in zip(esrc, edst)]
-        rev = [index[(d, s)] for s, d in zip(esrc, edst)]
+        # python lists go through numpy first: jnp.asarray walks a list
+        # element by element, which costs seconds at 2^18 processes
+        slot = np.asarray([slot_maps[d][s] for s, d in zip(esrc, edst)],
+                          np.int32)
+        rev = np.asarray([index[(d, s)] for s, d in zip(esrc, edst)],
+                         np.int32)
         self.E = E = len(esrc)
-        self._esrc = jnp.asarray(esrc, jnp.int32)
-        self._edst = jnp.asarray(edst, jnp.int32)
-        self._slot = jnp.asarray(slot, jnp.int32)
+        self._esrc = jnp.asarray(np.asarray(esrc, np.int32))
+        self._edst = jnp.asarray(np.asarray(edst, np.int32))
+        self._slot = jnp.asarray(slot)
         # flattened (dst, slot) key: several in-edges may share one halo
         # slot; delivery ties are broken by highest edge index (segment_max)
         # so the scatter is deterministic on every backend
-        self._halo_key = jnp.asarray(
-            [d * 4 + s for d, s in zip(edst, slot)], jnp.int32)
-        self._out_slot = jnp.asarray([OPP_IDX[s] for s in slot], jnp.int32)
-        self._rev = jnp.asarray(rev, jnp.int32)
+        self._halo_key = jnp.asarray(np.asarray(edst, np.int32) * 4 + slot)
+        self._out_slot = jnp.asarray(np.asarray(OPP_IDX, np.int32)[slot])
+        self._rev = jnp.asarray(rev)
         self._eids = jnp.arange(E, dtype=jnp.int32)
         self._pids = jnp.arange(n, dtype=jnp.int32)
 
@@ -146,9 +149,10 @@ class JaxEngine:
             self._loss = jnp.asarray(loss)
             self._flap = jnp.asarray(flap)
             self._dead = jnp.asarray(dead)
-        self._deg = jnp.asarray([topo.degree(p) for p in range(n)], jnp.int32)
-        self._cfactor = jnp.asarray(
-            [self.faults.compute_factor(p) for p in range(n)], jnp.float32)
+        self._deg = jnp.asarray(np.asarray(
+            [topo.degree(p) for p in range(n)], np.int32))
+        self._cfactor = jnp.asarray(np.asarray(
+            [self.faults.compute_factor(p) for p in range(n)], np.float32))
 
         # --- duct layout (DESIGN.md §10/§13): bucketed dense receiver-major
         # fast path (every topology), or the general edge-major path

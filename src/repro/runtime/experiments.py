@@ -48,6 +48,8 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import os
+import pathlib
 import time
 from typing import Dict, List, Optional, Sequence
 
@@ -484,6 +486,21 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+#: the compile cache's place when ``JAX_COMPILATION_CACHE_DIR`` is unset:
+#: one fixed directory in the checkout (git ignores it), so a later run of
+#: the same program finds its compiled executables again
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for an entry point.
+    ``JAX_COMPILATION_CACHE_DIR``, where set, places it (JAX reads the
+    variable itself); otherwise it lives at :data:`COMPILE_CACHE_DIR`."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+        jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+
+
 def main(argv: Optional[Sequence[str]] = None) -> List[dict]:
     parser = build_parser()
     args = parser.parse_args(argv)
@@ -495,6 +512,7 @@ def main(argv: Optional[Sequence[str]] = None) -> List[dict]:
         validate_run_config(args.run)
     except ValueError as e:
         parser.error(str(e))
+    use_compile_cache()
     families = list(FAMILIES) if args.family == "all" else [args.family]
     rows: List[dict] = []
     t0 = time.perf_counter()

@@ -100,7 +100,6 @@ def test_spmd_graphcolor_multidevice():
         from repro.core.conduit import torus_conduits
         from repro.core.modes import AsyncMode
         from repro.apps.graphcolor import spmd_step
-        from repro.launch.mesh import shard_map  # version-compat wrapper
 
         mesh = jax.make_mesh((2, 2), ("row", "col"))
         rowc, colc = torus_conduits(("row", "col"), AsyncMode.BEST_EFFORT)
@@ -116,10 +115,6 @@ def test_spmd_graphcolor_multidevice():
                 "key": key, "step": jnp.zeros((), jnp.int32),
             }
             def _vary(x):
-                # vma tagging only exists on current jax; older releases
-                # run with replication checking off and don't need it
-                if not hasattr(jax, "typeof"):
-                    return x
                 missing = tuple(a for a in ("row", "col")
                                 if a not in jax.typeof(x).vma)
                 return jax.lax.pvary(x, missing) if missing else x
@@ -131,8 +126,9 @@ def test_spmd_graphcolor_multidevice():
             return confs
 
         keys = jax.random.split(jax.random.PRNGKey(0), 4).reshape(2, 2, 2)
-        f = jax.jit(shard_map(body, mesh, in_specs=P("row", "col"),
-                              out_specs=P(("row", "col"))))
+        f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("row", "col"),
+                                  out_specs=P(("row", "col")),
+                                  check_vma=False))
         confs = np.asarray(f(keys))  # (400*4?) -> per-device concat
         per_dev = confs.reshape(4, -1) if confs.ndim == 1 else confs
         start = per_dev[..., :10].mean()

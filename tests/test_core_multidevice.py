@@ -29,7 +29,6 @@ def test_conduit_staleness_semantics():
         from jax.sharding import PartitionSpec as P
         from repro.core.conduit import Conduit
         from repro.core.modes import AsyncMode
-        from repro.launch.mesh import shard_map  # version-compat wrapper
 
         mesh = jax.make_mesh((8,), ("x",))
 
@@ -41,8 +40,9 @@ def test_conduit_staleness_semantics():
                 rec1, bufs = cond.exchange(val, bufs)
                 rec2, bufs = cond.exchange(val + 100, bufs)
                 return rec1["fwd"], rec2["fwd"]
-            f = jax.jit(shard_map(body, mesh, in_specs=P("x"),
-                                  out_specs=(P("x"), P("x"))))
+            f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("x"),
+                                      out_specs=(P("x"), P("x")),
+                                      check_vma=False))
             return f(jnp.arange(8))
 
         # mode 0: fresh values arrive in-step: rec1 = left neighbor rank
@@ -71,7 +71,6 @@ def test_gradient_exchange_modes():
         from jax.sharding import PartitionSpec as P
         from repro.core import collectives
         from repro.core.modes import AsyncMode
-        from repro.launch.mesh import shard_map  # version-compat wrapper
 
         mesh = jax.make_mesh((2, 4), ("pod", "data"))
 
@@ -81,9 +80,9 @@ def test_gradient_exchange_modes():
                 eff1, state = collectives.exchange_gradients(g, state, mode, "pod")
                 eff2, state = collectives.exchange_gradients(g * 10, state, mode, "pod")
                 return eff1, eff2
-            f = jax.jit(shard_map(body, mesh,
-                                  in_specs=P("pod"), out_specs=P("pod"),
-                                  axis_names={"pod"}))
+            f = jax.jit(jax.shard_map(body, mesh=mesh,
+                                      in_specs=P("pod"), out_specs=P("pod"),
+                                      axis_names={"pod"}, check_vma=False))
             g = jnp.array([1.0, 3.0])  # pod 0 grad=1, pod 1 grad=3
             return f(g)
 
@@ -114,7 +113,6 @@ def test_compressed_cross_pod_sum():
         from jax.sharding import PartitionSpec as P
         from repro.core import collectives
         from repro.optim.compression import Int8Compressor, TopKCompressor
-        from repro.launch.mesh import shard_map  # version-compat wrapper
 
         mesh = jax.make_mesh((2,), ("pod",))
 
@@ -123,8 +121,9 @@ def test_compressed_cross_pod_sum():
                 tree = {"w": g.reshape(4, 8)}
                 total, res = collectives.cross_pod_sum(tree, "pod", comp)
                 return total["w"], res["w"]
-            f = jax.jit(shard_map(body, mesh, in_specs=P("pod"),
-                                  out_specs=P("pod"), axis_names={"pod"}))
+            f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("pod"),
+                                      out_specs=P("pod"), axis_names={"pod"},
+                                      check_vma=False))
             return f(g)
 
         g = jax.random.normal(jax.random.PRNGKey(0), (2 * 4, 8))

@@ -96,6 +96,29 @@ def test_input_specs_shapes():
     assert "SPECS-OK" in r.stdout
 
 
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_placement(tmp_path, from_env):
+    """An entry point leaves ``JAX_COMPILATION_CACHE_DIR`` to JAX and sets
+    no other path; without it the cache lives at one fixed path in the
+    checkout."""
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(REPO, ".jax_cache")
+    if from_env:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    script = textwrap.dedent("""
+        import jax
+        from repro.runtime.experiments import use_compile_cache
+        use_compile_cache()
+        print(jax.config.jax_compilation_cache_dir)
+    """)
+    r = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                       text=True, env=env, timeout=120)
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.splitlines()[-1] == want
+
+
 @pytest.mark.slow
 def test_reduced_dryrun_on_debug_mesh():
     """Lower+compile a reduced config on a (2,2,2) mesh — validates the
@@ -110,12 +133,13 @@ def test_reduced_dryrun_on_debug_mesh():
         from repro.configs.smoke import reduce_for_smoke
         from repro.core.modes import AsyncMode
         from repro.launch import serve as serve_mod, train as train_mod
+        from repro.launch.mesh import make_debug_mesh
         from repro.launch.sharding import (param_specs, shardings_from_specs,
                                            with_pod_dim)
         from repro.models import lm, partitioning
         from repro.models.partitioning import MeshRules
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_debug_mesh((2, 2, 2), ("pod", "data", "model"))
         rules = MeshRules(mesh, dp=("data",), tp="model")
         cfg = reduce_for_smoke(get_config("deepseek-moe-16b"))
         spec = train_mod.TrainSpec(mode=AsyncMode.BEST_EFFORT)
@@ -139,7 +163,6 @@ def test_reduced_dryrun_on_debug_mesh():
             ).lower(state_like, batch)
             compiled = lowered.compile()
             ca = compiled.cost_analysis()
-            ca = ca[0] if isinstance(ca, list) else ca  # older-jax shape
             assert ca.get("flops", 0) > 0
         print("DRYRUN-SMALL-OK")
     """)

@@ -219,6 +219,29 @@ def test_duct_commit_matches_ref(impl):
                                       err_msg=f"{impl}: field {name}")
 
 
+@pytest.mark.parametrize("op", ["window", "commit"])
+def test_duct_kernels_ragged_lane_blocks(op):
+    """Enough rings for several lane blocks, the last one ragged: 2800
+    rings of 64 slots outgrow one block's VMEM budget, and a block short
+    of the whole ring axis is a multiple of 128 lanes, which 2800 is not.
+    Blocks are independent, so the kernel stays slot-exact with no
+    padding."""
+    rng = np.random.default_rng(23)
+    if op == "window":
+        args = _random_window_state(rng, n=700, d=4, C=64, L=2, cap=64)
+        ref = duct_window_ref(*args, max_pops=16)
+        out = duct_window(*map(jnp.asarray, args), max_pops=16,
+                          use_pallas=True, interpret=True)
+    else:
+        args = _random_commit_state(rng, R=2800, C=64, L=2, W=8)
+        ref = duct_commit_ref(*args)
+        out = duct_commit(*map(jnp.asarray, args), use_pallas=True,
+                          interpret=True)
+    for name, a, b in zip(ref._fields, ref, out):
+        np.testing.assert_array_equal(np.asarray(b), np.asarray(a),
+                                      err_msg=f"{op}: field {name}")
+
+
 # ---------------------------------------------------------------------------
 # W-fused superstep scheduler: bitwise vs per-window dense on EVERY topology
 # ---------------------------------------------------------------------------
