@@ -56,6 +56,7 @@ import warnings
 from typing import (Callable, Dict, List, Optional, Protocol, Sequence,
                     Tuple, Union, runtime_checkable)
 
+from repro.runtime import spans
 from repro.runtime.config import STRATEGY_KEYS, RunConfig
 from repro.runtime.faults import FaultModel
 from repro.runtime.simulator import SimConfig, SimResult, Simulator
@@ -123,15 +124,19 @@ def _make_event(app, cfg: SimConfig, faults: Optional[FaultModel],
 
 def _make_jax(app, cfg: SimConfig, faults: Optional[FaultModel],
               **kwargs) -> Engine:
-    # deferred imports: heavy jax machinery
     shards = kwargs.pop("shards", 1)
-    if shards and shards > 1:
-        from repro.runtime.engine_sharded import ShardedJaxEngine
-        return ShardedJaxEngine(app, cfg, faults, shards=shards, **kwargs)
-    # the unsharded engine understands window + superstep (the W-fused
-    # dense megakernel); _validate already rejected pipelined here
-    from repro.runtime.engine_jax import JaxEngine
-    return JaxEngine(app, cfg, faults, **kwargs)
+    # deferred imports: heavy jax machinery (Pallas and Mosaic among it),
+    # a set-up phase of its own
+    with spans.span("setup.import"):
+        if shards and shards > 1:
+            from repro.runtime.engine_sharded import ShardedJaxEngine as cls
+            kwargs["shards"] = shards
+        else:
+            # the unsharded engine understands window + superstep (the
+            # W-fused dense megakernel); _validate already rejected
+            # pipelined here
+            from repro.runtime.engine_jax import JaxEngine as cls
+    return cls(app, cfg, faults, **kwargs)
 
 
 _REGISTRY: Dict[str, EngineSpec] = {}

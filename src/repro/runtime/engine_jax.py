@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.modes import AsyncMode
+from repro.runtime import spans
 from repro.runtime.faults import FaultModel
 from repro.runtime.simulator import SimConfig, SimResult
 from repro.runtime.topologies import (
@@ -56,6 +57,7 @@ from repro.runtime.window_core import (  # noqa: F401  (re-exports: the RNG
     BARRIER_MODES as _BARRIER_MODES,
     LOCAL_RELEASE,
     make_dense_spec,
+    SEND,
     STREAM_APP,
     STREAM_LAT,
     STREAM_MUT,
@@ -80,6 +82,7 @@ class JaxEngine:
 
     name = "jax"
 
+    @spans.span("setup.engine")
     def __init__(self, app, cfg: SimConfig,
                  faults: Optional[FaultModel] = None,
                  *, max_pops: int = 16, chunk: int = 256,
@@ -99,91 +102,102 @@ class JaxEngine:
                 "runtime.topologies.Topology (experiments always inject one)")
         self.topo = topo
         self.n = n = app.n_processes
-        self.bapp = app.batched()
+        with spans.span("app"):
+            self.bapp = app.batched()
         self.core = WindowCore(cfg, self.bapp, n, max_pops=max_pops)
 
         # --- static edge plumbing (numpy, hoisted out of the scan) --------
-        esrc, edst, index = canonical_edges(topo)
-        slot_maps = [halo_slot_map(topo.neighbors[p]) for p in range(n)]
-        # python lists go through numpy first: jnp.asarray walks a list
-        # element by element, which costs seconds at 2^18 processes
-        slot = np.asarray([slot_maps[d][s] for s, d in zip(esrc, edst)],
-                          np.int32)
-        rev = np.asarray([index[(d, s)] for s, d in zip(esrc, edst)],
-                         np.int32)
+        with spans.span("edges"):
+            esrc, edst, index = canonical_edges(topo)
+            slot_maps = [halo_slot_map(topo.neighbors[p]) for p in range(n)]
+            # python lists go through numpy first: jnp.asarray walks a list
+            # element by element, which costs seconds at 2^18 processes
+            slot = np.asarray([slot_maps[d][s] for s, d in zip(esrc, edst)],
+                              np.int32)
+            rev = np.asarray([index[(d, s)] for s, d in zip(esrc, edst)],
+                             np.int32)
+            lat = np.empty(len(esrc), np.float32)
+            loss = np.empty(len(esrc), np.float32)
+            flap = np.empty(len(esrc), np.float32)
+            dead = np.empty(len(esrc), bool)
+            for e, (s, d) in enumerate(zip(esrc, edst)):
+                base = cfg.base_latency
+                if (cfg.intra_node_latency is not None
+                        and topo.same_node(s, d)):
+                    base = cfg.intra_node_latency
+                lat[e] = base * self.faults.link_factor(s, d)
+                loss[e] = self.faults.loss_prob(s, d)
+                flap[e] = self.faults.flap_frac(s, d)
+                dead[e] = self.faults.is_crashed(d)
+            crashed_np = np.asarray(
+                [self.faults.is_crashed(p) for p in range(n)], bool)
+            deg = np.asarray([topo.degree(p) for p in range(n)], np.int32)
+            cfactor = np.asarray(
+                [self.faults.compute_factor(p) for p in range(n)],
+                np.float32)
         self.E = E = len(esrc)
-        self._esrc = jnp.asarray(np.asarray(esrc, np.int32))
-        self._edst = jnp.asarray(np.asarray(edst, np.int32))
-        self._slot = jnp.asarray(slot)
-        # flattened (dst, slot) key: several in-edges may share one halo
-        # slot; delivery ties are broken by highest edge index (segment_max)
-        # so the scatter is deterministic on every backend
-        self._halo_key = jnp.asarray(np.asarray(edst, np.int32) * 4 + slot)
-        self._out_slot = jnp.asarray(np.asarray(OPP_IDX, np.int32)[slot])
-        self._rev = jnp.asarray(rev)
-        self._eids = jnp.arange(E, dtype=jnp.int32)
-        self._pids = jnp.arange(n, dtype=jnp.int32)
-
-        lat = np.empty(E, np.float32)
-        loss = np.empty(E, np.float32)
-        flap = np.empty(E, np.float32)
-        dead = np.empty(E, bool)
-        for e, (s, d) in enumerate(zip(esrc, edst)):
-            base = cfg.base_latency
-            if cfg.intra_node_latency is not None and topo.same_node(s, d):
-                base = cfg.intra_node_latency
-            lat[e] = base * self.faults.link_factor(s, d)
-            loss[e] = self.faults.loss_prob(s, d)
-            flap[e] = self.faults.flap_frac(s, d)
-            dead[e] = self.faults.is_crashed(d)
-        self._lat_base = jnp.asarray(lat)
         # typed faults (DESIGN.md §14): per-edge loss/flap probabilities and
         # dead-destination flags, plus the crashed-process mask.  All static
         # per run — TimelineEvent faults re-instantiate the engine per epoch
-        crashed_np = np.asarray(
-            [self.faults.is_crashed(p) for p in range(n)], bool)
         self._has_faults = bool(loss.any() or flap.any() or dead.any())
         self._any_crashed = bool(crashed_np.any())
-        self._crashed = jnp.asarray(crashed_np)
-        if self._has_faults:
-            self._loss = jnp.asarray(loss)
-            self._flap = jnp.asarray(flap)
-            self._dead = jnp.asarray(dead)
-        self._deg = jnp.asarray(np.asarray(
-            [topo.degree(p) for p in range(n)], np.int32))
-        self._cfactor = jnp.asarray(np.asarray(
-            [self.faults.compute_factor(p) for p in range(n)], np.float32))
 
         # --- duct layout (DESIGN.md §10/§13): bucketed dense receiver-major
         # fast path (every topology), or the general edge-major path
-        self.lplan = plan_layout(topo, layout)
+        with spans.span("layout"):
+            self.lplan = plan_layout(topo, layout)
         self.layout = self.lplan.kind
-        if self.layout == "dense":
-            lp = self.lplan
-            self._spec = make_dense_spec(lp)
-            self.R = R = int(lp.n_rows)
-            # flat (R,) row tables; dead padding rows carry sentinel
-            # src == n / eid == E and live == False
-            j = np.arange(R) - lp.row_start[lp.dst]
-            self._d_src = jnp.asarray(lp.src)
-            self._d_dst = jnp.asarray(lp.dst)
-            self._d_rev = jnp.asarray(lp.rev)
-            self._d_eid = jnp.asarray(lp.eid)
-            self._d_live = jnp.asarray(lp.live)
-            # row j of a receiver block feeds halo slot j % 4, so the
-            # sender writes the opposite slot — same OPP_IDX formula as
-            # the edge-major path, computed per flat row
-            self._d_out_slot = jnp.asarray(
-                np.asarray(OPP_IDX, np.int32)[j % 4])
-            self._d_lat = jnp.asarray(np.concatenate(
-                [lat, np.zeros(1, np.float32)])[lp.eid])
+
+        with spans.span("tables"):
+            self._esrc = jnp.asarray(np.asarray(esrc, np.int32))
+            self._edst = jnp.asarray(np.asarray(edst, np.int32))
+            self._slot = jnp.asarray(slot)
+            # flattened (dst, slot) key: several in-edges may share one
+            # halo slot; delivery ties are broken by highest edge index
+            # (segment_max) so the scatter is deterministic on every
+            # backend
+            self._halo_key = jnp.asarray(np.asarray(edst, np.int32) * 4
+                                         + slot)
+            self._out_slot = jnp.asarray(np.asarray(OPP_IDX, np.int32)[slot])
+            self._rev = jnp.asarray(rev)
+            self._eids = jnp.arange(E, dtype=jnp.int32)
+            self._pids = jnp.arange(n, dtype=jnp.int32)
+            self._lat_base = jnp.asarray(lat)
+            self._crashed = jnp.asarray(crashed_np)
             if self._has_faults:
-                self._d_loss = jnp.asarray(np.concatenate(
-                    [loss, np.zeros(1, np.float32)])[lp.eid])
-                self._d_flap = jnp.asarray(np.concatenate(
-                    [flap, np.zeros(1, np.float32)])[lp.eid])
-                self._d_dead = jnp.asarray(np.concatenate(
-                    [dead, np.zeros(1, bool)])[lp.eid])
+                self._loss = jnp.asarray(loss)
+                self._flap = jnp.asarray(flap)
+                self._dead = jnp.asarray(dead)
+            self._deg = jnp.asarray(deg)
+            self._cfactor = jnp.asarray(cfactor)
+            if self.layout == "dense":
+                lp = self.lplan
+                self._spec = make_dense_spec(lp)
+                self.R = R = int(lp.n_rows)
+                # flat (R,) row tables; dead padding rows carry sentinel
+                # src == n / eid == E and live == False
+                j = np.arange(R) - lp.row_start[lp.dst]
+                self._d_src = jnp.asarray(lp.src)
+                self._d_dst = jnp.asarray(lp.dst)
+                self._d_rev = jnp.asarray(lp.rev)
+                self._d_eid = jnp.asarray(lp.eid)
+                self._d_live = jnp.asarray(lp.live)
+                # row j of a receiver block feeds halo slot j % 4, so the
+                # sender writes the opposite slot — same OPP_IDX formula as
+                # the edge-major path, computed per flat row
+                self._d_out_slot = jnp.asarray(
+                    np.asarray(OPP_IDX, np.int32)[j % 4])
+                self._d_lat = jnp.asarray(np.concatenate(
+                    [lat, np.zeros(1, np.float32)])[lp.eid])
+                if self._has_faults:
+                    self._d_loss = jnp.asarray(np.concatenate(
+                        [loss, np.zeros(1, np.float32)])[lp.eid])
+                    self._d_flap = jnp.asarray(np.concatenate(
+                        [flap, np.zeros(1, np.float32)])[lp.eid])
+                    self._d_dead = jnp.asarray(np.concatenate(
+                        [dead, np.zeros(1, bool)])[lp.eid])
+        spans.count("setup.processes", n)
+        spans.count("setup.ducts", E)
         if scheduler == "superstep" and self.layout != "edge":
             w = self.superstep_windows
             if w < 2:
@@ -230,13 +244,15 @@ class JaxEngine:
             return self.core.dense_rings(self.R)
         return self.core.edge_rings(self.E)
 
+    @spans.span("setup.carry")
     def _init_carry(self, seed: int) -> Dict[str, jax.Array]:
         n = self.n
         bapp = self.bapp
         seed_arr = jnp.asarray(seed, jnp.int32)
         t0 = self.core.base_total * self._step_factor(
             seed_arr, jnp.zeros(n, jnp.int32))
-        state, halo = bapp.init(seed)
+        with spans.span("app"):
+            state, halo = bapp.init(seed)
         extra: Dict[str, jax.Array] = {}
         if self._any_crashed:
             # a crashed process's clock IS its next barrier arrival: +inf
@@ -312,8 +328,10 @@ class JaxEngine:
             # count), NOT the lockstep window counter: a process's c-th
             # send draws the same jitter no matter which window — or
             # scheduler — it executes under, so W-invariance is exact
-            lat = self._lat_base * lognormal_factor(
-                cfg.latency_sigma, seed, STREAM_LAT, self._eids, steps[esrc])
+            with jax.named_scope(SEND):
+                lat = self._lat_base * lognormal_factor(
+                    cfg.latency_sigma, seed, STREAM_LAT, self._eids,
+                    steps[esrc])
             act_e = active[esrc]
             send_act = act_e
             if self._has_faults:
@@ -388,10 +406,11 @@ class JaxEngine:
             # same (edge, sender step) latency keying as the edge-major
             # path: flat row r's sender is src[r] (sentinel-clipped on
             # dead rows, whose draws are masked off by `live`)
-            src_c = jnp.clip(self._d_src, 0, self.n - 1)
-            lat = self._d_lat * lognormal_factor(
-                cfg.latency_sigma, seed, STREAM_LAT, self._d_eid,
-                steps[src_c])
+            with jax.named_scope(SEND):
+                src_c = jnp.clip(self._d_src, 0, self.n - 1)
+                lat = self._d_lat * lognormal_factor(
+                    cfg.latency_sigma, seed, STREAM_LAT, self._d_eid,
+                    steps[src_c])
             km = None
             if self._has_faults:
                 km = core.fault_masks(
@@ -434,6 +453,7 @@ class JaxEngine:
     # ------------------------------------------------------------------
     def _get_runner(self):
         if self._runner is None:
+            spans.key_compiles_by_names()
             if self.layout == "dense" and self.scheduler == "superstep":
                 W = self.superstep_windows
                 sup = max(1, self.chunk // W)
@@ -466,35 +486,50 @@ class JaxEngine:
         carry = jax.tree_util.tree_map(
             lambda *xs: jnp.stack(xs), *carries)
         runner = self._get_runner()
-        windows = 0
+        windows = chunks = 0
         prev_done = None
         while windows < self._max_windows:
-            carry = runner(carry)
+            with spans.span("loop.dispatch"):
+                carry = runner(carry)
+                # crashed processes never reach the horizon; the probe
+                # treats them as terminally stopped
+                all_done = (jnp.all(carry["done"] | self._crashed)
+                            if self._any_crashed else jnp.all(carry["done"]))
             windows += self._windows_per_call
+            chunks += 1
             # pipelined early-exit probe: enqueue this chunk's tiny done
             # reduction, but only *read* the previous chunk's — the host
             # blocks on a result whose chunk already finished while the
             # next chunk keeps the device busy, so the dispatch pipeline
             # never drains.  Costs one extra (state-invariant: every
             # process is inactive) chunk after the run completes.
-            # crashed processes never reach the horizon; the probe treats
-            # them as terminally stopped
-            all_done = (jnp.all(carry["done"] | self._crashed)
-                        if self._any_crashed else jnp.all(carry["done"]))
-            if prev_done is not None and bool(prev_done):
-                break
+            if prev_done is not None:
+                with spans.span("loop.probe"):
+                    stop = bool(prev_done)
+                if stop:
+                    break
             prev_done = all_done
-        carry = jax.device_get(carry)
+        spans.count("loop.chunks", chunks)
+        with spans.span("loop.fetch"):
+            carry = jax.device_get(carry)
+        spans.count("loop.fetch_bytes",
+                    sum(x.nbytes for x in jax.tree_util.tree_leaves(carry)))
         if getattr(self, "debug_keep_carry", False):
             self._final_carry = carry
-        return [self._assemble(carry, r) for r in range(len(seeds))]
+        with spans.span("loop.assemble"):
+            return [self._assemble(carry, r) for r in range(len(seeds))]
 
     # ------------------------------------------------------------------
     def _assemble(self, carry, r: int) -> SimResult:
         app_state = jax.tree_util.tree_map(lambda x: x[r], carry["app"])
-        return self.core.assemble(
-            carry, r, np.asarray(self._deg, np.int64),
-            self.bapp.quality(app_state),
-            app_state=(self.bapp.export_state(app_state)
-                       if self.cfg.carry_app_state
-                       and hasattr(self.bapp, "export_state") else None))
+        with spans.span("assemble.quality"):
+            quality = self.bapp.quality(app_state)
+        with spans.span("assemble.qos"):
+            result = self.core.assemble(
+                carry, r, np.asarray(self._deg, np.int64), quality,
+                app_state=(self.bapp.export_state(app_state)
+                           if self.cfg.carry_app_state
+                           and hasattr(self.bapp, "export_state") else None))
+        spans.count("assemble.processes", len(result.updates))
+        spans.count("assemble.reports", len(result.qos))
+        return result

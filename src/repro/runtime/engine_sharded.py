@@ -96,6 +96,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.modes import AsyncMode
 from repro.launch.mesh import SHARD_AXIS, make_shard_mesh
+from repro.runtime import spans
 from repro.runtime.engine_jax import JaxEngine
 from repro.runtime.simulator import SimResult
 from repro.runtime.topologies import contiguous_partition
@@ -992,6 +993,7 @@ class ShardedJaxEngine(JaxEngine):
     # ------------------------------------------------------------------
     def _get_runner(self):
         if self._runner is None:
+            spans.key_compiles_by_names()
             W = self.superstep
             final = (self._final_window_pipelined
                      if self.scheduler == "pipelined"
@@ -1043,30 +1045,41 @@ class ShardedJaxEngine(JaxEngine):
                     lambda _: NamedSharding(self.mesh, P(SHARD_AXIS)),
                     self._statics))
         runner = self._get_runner()
-        windows = 0
+        windows = chunks = 0
         prev_done = None
         while windows < self._max_windows:
-            carry = runner(self._statics_sharded, carry)
+            with spans.span("loop.dispatch"):
+                carry = runner(self._statics_sharded, carry)
+                # crashed processes never reach the horizon; the probe
+                # treats them as terminally stopped (position order, like
+                # the carry)
+                all_done = (jnp.all(carry["done"] | self._crashed_pos)
+                            if self._any_crashed else jnp.all(carry["done"]))
             windows += self._windows_per_dispatch
+            chunks += 1
             # pipelined early-exit probe (same pattern as JaxEngine): only
             # the *previous* dispatch's done reduction is read, so the host
             # never stalls the mesh on a fresh round-trip — at the cost of
             # one state-invariant extra dispatch after the run completes.
-            # crashed processes never reach the horizon; the probe treats
-            # them as terminally stopped (position order, like the carry)
-            all_done = (jnp.all(carry["done"] | self._crashed_pos)
-                        if self._any_crashed else jnp.all(carry["done"]))
-            if prev_done is not None and bool(prev_done):
-                break
+            if prev_done is not None:
+                with spans.span("loop.probe"):
+                    stop = bool(prev_done)
+                if stop:
+                    break
             prev_done = all_done
+        spans.count("loop.chunks", chunks)
         if (self.scheduler == "pipelined" and
                 self.cfg.mode != AsyncMode.NO_COMM):
             # epilogue flush: deliver/fold whatever is still in flight so
             # message conservation holds even when the loop exits with a
             # live superstep's exchange un-consumed
             carry = self._get_flusher()(self._statics_sharded, carry)
-        carry = jax.device_get(carry)
+        with spans.span("loop.fetch"):
+            carry = jax.device_get(carry)
+        spans.count("loop.fetch_bytes",
+                    sum(x.nbytes for x in jax.tree_util.tree_leaves(carry)))
         carry = self._to_canonical_layout(carry)
         if getattr(self, "debug_keep_carry", False):
             self._final_carry = carry
-        return [self._assemble(carry, r) for r in range(len(seeds))]
+        with spans.span("loop.assemble"):
+            return [self._assemble(carry, r) for r in range(len(seeds))]

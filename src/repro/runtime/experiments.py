@@ -46,6 +46,7 @@ runs every family.  See EXPERIMENTS.md for the full matrix.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import json
 import os
@@ -56,6 +57,7 @@ from typing import Dict, List, Optional, Sequence
 from repro.core.modes import AsyncMode
 from repro.core.qos import METRICS, aggregate_reports, aggregate_timeseries
 from repro.core.slo import SloPolicy
+from repro.runtime import spans
 from repro.runtime.config import RunConfig
 from repro.runtime.engine import (ENGINES, make_engine, run_replicates,
                                   validate_run_config)
@@ -483,6 +485,12 @@ def build_parser() -> argparse.ArgumentParser:
                    help="burn rate above which an interval is marked "
                         "burning (sustained breach)")
     p.add_argument("--json", default=None, help="write rows to this path")
+    p.add_argument("--trace-dir", default=None,
+                   help="record a jax.profiler trace of the whole run "
+                        "into this directory: the program's host spans "
+                        "and, with --engine jax, the device ops by window "
+                        "phase; print the spans' host seconds and the "
+                        "counters")
     return p
 
 
@@ -516,9 +524,17 @@ def main(argv: Optional[Sequence[str]] = None) -> List[dict]:
     families = list(FAMILIES) if args.family == "all" else [args.family]
     rows: List[dict] = []
     t0 = time.perf_counter()
-    for fam in families:
-        rows.extend(FAMILIES[fam](args))
+    with contextlib.ExitStack() as stack:
+        if args.trace_dir:
+            import jax
+            spans.reset()
+            stack.enter_context(jax.profiler.trace(args.trace_dir))
+        for fam in families:
+            rows.extend(FAMILIES[fam](args))
     print(f"done in {time.perf_counter() - t0:.1f}s wall")
+    if args.trace_dir:
+        print(spans.report())
+        print(f"trace written under {args.trace_dir}")
     if args.json:
         with open(args.json, "w") as f:
             json.dump(rows, f, indent=1, default=float)

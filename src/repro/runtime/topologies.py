@@ -27,6 +27,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.runtime import spans
 from repro.runtime.faults import _splitmix64
 
 logger = logging.getLogger(__name__)
@@ -505,6 +506,7 @@ TOPOLOGIES = {
 }
 
 
+@spans.span("setup.topology")
 def make_topology(name: str, n: int, **kwargs) -> Topology:
     """Build a registered topology by name for ``n`` processes."""
     try:

@@ -55,6 +55,15 @@ from repro.kernels.duct_exchange.ops import (
 from repro.runtime.faults import STREAM_FLAP, STREAM_LOSS  # noqa: F401
 from repro.runtime.simulator import SimResult
 
+#: the ``jax.named_scope`` of each window phase: every operation a phase
+#: emits carries the name in its ``op_name`` metadata, so a device trace
+#: splits the chunk program's time by phase (the snapshot scatter nests
+#: inside the close)
+DRAIN, COMPUTE, SEND, CLOSE, SNAPSHOT, COMMIT = (
+    "window.drain", "window.compute", "window.send", "window.close",
+    "window.snapshot", "window.commit")
+
+
 #: modes whose processes stop at a barrier and wait for a global release
 BARRIER_MODES = (AsyncMode.BARRIER_EVERY_STEP, AsyncMode.ROLLING_BARRIER,
                  AsyncMode.FIXED_BARRIER)
@@ -268,6 +277,7 @@ class WindowCore:
                           f * np.float32(cfg.stall_factor), f)
         return f * cfactor
 
+    @jax.named_scope(SEND)
     def fault_masks(self, seed, t_src, steps_src, eids, loss, flap,
                     flap_period, dead):
         """Per-edge typed-fault send masks (DESIGN.md §14).
@@ -358,6 +368,7 @@ class WindowCore:
     # ------------------------------------------------------------------
     # Phase 1: drain
     # ------------------------------------------------------------------
+    @jax.named_scope(DRAIN)
     def drain(self, carry, t_rows, act_rows, *, halo_key, n_halo,
               dst, n_dst, dense_spec: Optional[DenseSpec] = None):
         """Edge-major drain over a block of rings living on their
@@ -438,6 +449,7 @@ class WindowCore:
                 recv_sums = recv_sums.at[b.members].add(sums_b, mode="drop")
         return halo, recv_sums
 
+    @jax.named_scope(DRAIN)
     def window_dense(self, carry, t, active, *, spec: DenseSpec):
         """Dense-layout drain phase: per degree bucket, one fused
         ``duct_window`` pass applies the previous window's staged sends,
@@ -515,6 +527,7 @@ class WindowCore:
             c_touch=carry["c_touch"] + touch_r)
         return new, drained_r
 
+    @jax.named_scope(DRAIN)
     def window_dense_fused(self, carry, t, active, *, spec: DenseSpec,
                            dst_row):
         """One window of the W-fused superstep scheduler (DESIGN.md §13).
@@ -615,6 +628,7 @@ class WindowCore:
             pb_cnt=pb_cnt, pb_avail=pb_avail, pb_touch=pb_touch,
             pb_pay=pb_pay), recv_sums[:, 0]
 
+    @jax.named_scope(COMMIT)
     def commit_superstep(self, carry):
         """Superstep epilogue for the fused scheduler: ONE ``duct_commit``
         launch folds the whole superstep's accepted pushes into the base
@@ -643,6 +657,7 @@ class WindowCore:
     # ------------------------------------------------------------------
     # Phase 2: compute
     # ------------------------------------------------------------------
+    @jax.named_scope(COMPUTE)
     def compute(self, carry, active, halo, pids):
         """The application's actual batched compute, masked by activity.
         Returns ``(app_state, edges_out, steps)``."""
@@ -659,6 +674,7 @@ class WindowCore:
     # ------------------------------------------------------------------
     # Phase 3: send (edge-major)
     # ------------------------------------------------------------------
+    @jax.named_scope(SEND)
     def send_edge(self, rings, now, act, lat, touch, payload,
                   src, n_src, *, sorted_src: bool = False,
                   want_sums: bool = True) -> SendPhase:
@@ -694,6 +710,7 @@ class WindowCore:
     # ------------------------------------------------------------------
     # Phase 3': stage (dense layout)
     # ------------------------------------------------------------------
+    @jax.named_scope(SEND)
     def stage_dense(self, carry, u, t, active, edges_out, lat,
                     *, src, rev, out_slot, live, deg, spec: DenseSpec,
                     kill_masks=None):
@@ -754,6 +771,7 @@ class WindowCore:
     # ------------------------------------------------------------------
     # Phase 4: close window
     # ------------------------------------------------------------------
+    @jax.named_scope(CLOSE)
     def close_window(self, u, active, drained_r, *, pids, deg, cfactor,
                      release):
         """Shared window tail: QoS snapshot scatter, termination, barrier
@@ -785,21 +803,22 @@ class WindowCore:
         else:
             pending = (drained_r.astype(jnp.float32) * np.float32(
                 cfg.per_message_cost) + pull_cost)
-        snap_idx = u["snap_idx"]
-        thr = (np.float32(cfg.snapshot_warmup) +
-               snap_idx.astype(jnp.float32) * np.float32(
-                   cfg.snapshot_interval))
-        snap_due = active & (t >= thr) & (snap_idx < self.S)
-        row = jnp.stack([
-            steps.astype(jnp.float32), u["c_touch"].astype(jnp.float32),
-            u["c_att"].astype(jnp.float32), u["c_ok"].astype(jnp.float32),
-            u["c_drop"].astype(jnp.float32),
-            u["c_laden"].astype(jnp.float32),
-            u["c_msgs"].astype(jnp.float32), t], axis=1)
-        snap = u["snap"].at[
-            jnp.where(snap_due, jnp.arange(n, dtype=jnp.int32), n),
-            snap_idx].set(row, mode="drop")
-        snap_idx = snap_idx + snap_due
+        with jax.named_scope(SNAPSHOT):
+            snap_idx = u["snap_idx"]
+            thr = (np.float32(cfg.snapshot_warmup) +
+                   snap_idx.astype(jnp.float32) * np.float32(
+                       cfg.snapshot_interval))
+            snap_due = active & (t >= thr) & (snap_idx < self.S)
+            row = jnp.stack([
+                steps.astype(jnp.float32), u["c_touch"].astype(jnp.float32),
+                u["c_att"].astype(jnp.float32), u["c_ok"].astype(jnp.float32),
+                u["c_drop"].astype(jnp.float32),
+                u["c_laden"].astype(jnp.float32),
+                u["c_msgs"].astype(jnp.float32), t], axis=1)
+            snap = u["snap"].at[
+                jnp.where(snap_due, jnp.arange(n, dtype=jnp.int32), n),
+                snap_idx].set(row, mode="drop")
+            snap_idx = snap_idx + snap_due
 
         # --- termination / barriers / time advance ------------------------
         newly_done = active & (t >= np.float32(cfg.duration))
