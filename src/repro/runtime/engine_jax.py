@@ -12,7 +12,7 @@ barriers).  Per window it composes the shared window-phase core
                 latency-delayed availability) + halo-winner select
   2. compute    halo scatter + the application's *actual* batched compute
   3. send       edge-parallel send attempt (capacity drop, latency stamp)
-  4. close      incremental QoS counters + O(1) snapshot scatter,
+  4. close      incremental QoS counters + snapshot write (masked select),
                 termination, barriers, virtual-time advance
 
 All stochastic draws are counter-based splitmix-style hashes evaluated

@@ -13,8 +13,8 @@ lockstep-window phases:
              full, latency stamp), sender-side QoS counters
   stage      the dense layout's eager send decision (ring writes ride
              into the next window's fused ``duct_window`` pass)
-  close      QoS snapshot scatter, termination, barrier bookkeeping and
-             the virtual-time advance
+  close      QoS snapshot write (masked select), termination, barrier
+             bookkeeping and the virtual-time advance
 
 Before this module existed each engine reimplemented all of them; now the
 engines are thin compositions.  What stays engine-specific is exactly the
@@ -57,7 +57,7 @@ from repro.runtime.simulator import SimResult
 
 #: the ``jax.named_scope`` of each window phase: every operation a phase
 #: emits carries the name in its ``op_name`` metadata, so a device trace
-#: splits the chunk program's time by phase (the snapshot scatter nests
+#: splits the chunk program's time by phase (the snapshot write nests
 #: inside the close)
 DRAIN, COMPUTE, SEND, CLOSE, SNAPSHOT, COMMIT = (
     "window.drain", "window.compute", "window.send", "window.close",
@@ -774,8 +774,8 @@ class WindowCore:
     @jax.named_scope(CLOSE)
     def close_window(self, u, active, drained_r, *, pids, deg, cfactor,
                      release):
-        """Shared window tail: QoS snapshot scatter, termination, barrier
-        bookkeeping, and the virtual-time advance.
+        """Shared window tail: QoS snapshot write (masked select),
+        termination, barrier bookkeeping, and the virtual-time advance.
 
         ``release`` picks where the barrier-release reductions run:
         :data:`LOCAL_RELEASE` on one device, a :class:`MeshRelease` over
@@ -787,7 +787,6 @@ class WindowCore:
         mode = cfg.mode
         barriered = mode in BARRIER_MODES
         t, steps = u["t"], u["steps"]
-        n = t.shape[0]
         done, waiting = u["done"], u["waiting"]
         # rolling barriers meter their quantum on the WORK clock: compute
         # plus the (degree-fixed) halo pull cost, with per-message handling
@@ -815,9 +814,13 @@ class WindowCore:
                 u["c_drop"].astype(jnp.float32),
                 u["c_laden"].astype(jnp.float32),
                 u["c_msgs"].astype(jnp.float32), t], axis=1)
-            snap = u["snap"].at[
-                jnp.where(snap_due, jnp.arange(n, dtype=jnp.int32), n),
-                snap_idx].set(row, mode="drop")
+            # a masked select over the whole buffer, not a scatter: under
+            # vmap XLA lifts a scatter to the scan's loop level with
+            # relayouts of the buffer around it.  ``snap_idx < S`` already
+            # gates ``snap_due``, so a full buffer takes no row.
+            into = ((jnp.arange(self.S, dtype=jnp.int32)[None, :]
+                     == snap_idx[:, None]) & snap_due[:, None])
+            snap = jnp.where(into[..., None], row[:, None, :], u["snap"])
             snap_idx = snap_idx + snap_due
 
         # --- termination / barriers / time advance ------------------------

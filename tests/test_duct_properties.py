@@ -27,7 +27,8 @@ import jax.numpy as jnp
 from repro.kernels.duct_exchange.ops import duct_exchange_jnp, duct_window_jnp
 from repro.kernels.duct_exchange.ref import duct_exchange_ref, duct_window_ref
 from repro.runtime.simulator import SimConfig
-from repro.runtime.window_core import BucketSlab, DenseSpec, WindowCore
+from repro.runtime.window_core import (LOCAL_RELEASE, BucketSlab, DenseSpec,
+                                       WindowCore)
 
 try:
     from hypothesis import given, settings, strategies as hyp_st
@@ -660,6 +661,83 @@ def test_window_core_dense_phases_seeded(seed, n, d, C, max_pops, steps):
 @pytest.mark.parametrize("seed,n,d,C,max_pops,steps", CORE_EDGE_CASES)
 def test_shadow_buffer_properties_seeded(seed, n, d, C, max_pops, steps):
     run_shadow_sequence(seed, n, d, C, max_pops, steps)
+
+
+# ---------------------------------------------------------------------------
+# close_window's snapshot write against the scatter it replaced: a due
+# process's QoS row lands in slot snap_idx, every other slot keeps its bits,
+# and a full buffer (snap_idx == S) takes nothing, as mode="drop" did.
+# ---------------------------------------------------------------------------
+def _snapshot_scatter_oracle(core, u, active):
+    cfg, n = core.cfg, u["t"].shape[0]
+    snap_idx, t = u["snap_idx"], u["t"]
+    thr = (np.float32(cfg.snapshot_warmup) +
+           snap_idx.astype(jnp.float32) * np.float32(cfg.snapshot_interval))
+    due = active & (t >= thr) & (snap_idx < core.S)
+    row = jnp.stack([u[k].astype(jnp.float32) for k in (
+        "steps", "c_touch", "c_att", "c_ok", "c_drop", "c_laden",
+        "c_msgs")] + [t], axis=1)
+    snap = u["snap"].at[jnp.where(due, jnp.arange(n, dtype=jnp.int32), n),
+                        snap_idx].set(row, mode="drop")
+    return snap, snap_idx + due
+
+
+def _snapshot_carry(core, rng, n, case):
+    """A carry whose processes are due for a snapshot as ``case`` says,
+    with random counters and a buffer already holding random rows."""
+    cfg, S = core.cfg, core.S
+    snap_idx = rng.integers(0, S, n).astype(np.int32)
+    if case == "full":
+        snap_idx[::2] = S
+    thr = (np.float32(cfg.snapshot_warmup) +
+           snap_idx.astype(np.float32) * np.float32(cfg.snapshot_interval))
+    past = {"none": np.zeros(n, bool),
+            "some": np.arange(n) % 3 == 0}.get(case, np.ones(n, bool))
+    t = np.where(past, thr + np.float32(1e-3), thr - np.float32(1e-3))
+    active = (np.arange(n) % 2 == 0) if case == "inactive" else np.ones(
+        n, bool)
+    ints = {k: rng.integers(0, 1 << 20, n).astype(np.int32) for k in (
+        "steps", "c_touch", "c_att", "c_ok", "c_drop", "c_laden", "c_msgs",
+        "barrier_seq")}
+    u = dict(ints, t=t.astype(np.float32), snap_idx=snap_idx,
+             snap=rng.standard_normal((n, S, 8)).astype(np.float32),
+             done=np.zeros(n, bool), waiting=np.zeros(n, bool),
+             last_release=np.zeros(n, np.float32),
+             pending=np.zeros(n, np.float32), seed=np.int32(rng.integers(
+                 0, 1 << 30)), k=np.int32(0))
+    return {k: jnp.asarray(v) for k, v in u.items()}, jnp.asarray(active)
+
+
+@pytest.mark.parametrize("replicates", [1, 2])
+@pytest.mark.parametrize("case", ["none", "some", "all", "full", "inactive"])
+def test_close_window_snapshot_write_matches_scatter(case, replicates):
+    n = 24
+    core = _make_core(n, C=4, max_pops=2)
+    rng = np.random.default_rng(17)
+    carries = [_snapshot_carry(core, rng, n, case) for _ in range(replicates)]
+    kw = dict(pids=jnp.arange(n, dtype=jnp.int32),
+              deg=jnp.full(n, 4, jnp.int32),
+              cfactor=jnp.ones(n, jnp.float32), release=LOCAL_RELEASE)
+
+    def close(u, active):
+        out = core.close_window(u, active, jnp.zeros(n, jnp.int32), **kw)
+        return out["snap"], out["snap_idx"]
+
+    if replicates == 1:
+        got = [close(*carries[0])]
+    else:
+        u, active = jax.tree.map(lambda *xs: jnp.stack(xs), *carries)
+        snap, idx = jax.vmap(close)(u, active)
+        got = [(snap[r], idx[r]) for r in range(replicates)]
+    for (u, active), (snap, idx) in zip(carries, got):
+        want_snap, want_idx = _snapshot_scatter_oracle(core, u, active)
+        np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_idx))
+        np.testing.assert_array_equal(
+            np.asarray(snap).view(np.uint32),
+            np.asarray(want_snap).view(np.uint32))
+        written = int(np.sum(np.asarray(idx) - np.asarray(u["snap_idx"])))
+        assert written == {"none": 0, "some": n // 3, "all": n,
+                           "full": n // 2, "inactive": n // 2}[case]
 
 
 def run_fault_mask_sequence(seed: int, n: int, d: int, C: int,

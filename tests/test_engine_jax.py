@@ -10,6 +10,8 @@ specific to the jax engine itself:
   - runs are deterministic in the seed, and vmapped replicates are
     independent and identical to single runs.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -140,3 +142,33 @@ def test_best_effort_beats_barrier_rate_on_jax():
     assert r3.update_rate_per_cpu > 2.0 * r0.update_rate_per_cpu
     # barrier-every-step stays in lockstep
     assert max(r0.updates) - min(r0.updates) <= 1
+
+
+# ---------------------------------------------------------------------------
+# The chunk program writes the QoS snapshot buffer without a scatter
+# ---------------------------------------------------------------------------
+def _scatter_result_types(stablehlo: str):
+    """The result type of every ``stablehlo.scatter`` in the text."""
+    return [m.group(1) for m in re.finditer(
+        r'"stablehlo\.scatter".*?\}\) : \([^\n]*\) -> (tensor<[^>]*>)',
+        stablehlo, re.S)]
+
+
+@pytest.mark.parametrize("scheduler,W", [("window", 1), ("superstep", 8)])
+def test_chunk_writes_snapshots_without_scatter(scheduler, W):
+    """Under ``vmap`` a scatter into the carried ``(n, S, 8)`` buffer is
+    lifted to the scan's loop level with relayouts around it; the snapshot
+    write is a masked select, and a scatter of that shape must not come
+    back."""
+    from repro.runtime.config import RunConfig
+    from repro.runtime.engine import make_engine
+
+    eng = make_engine(
+        RunConfig(engine="jax", scheduler=scheduler, superstep_windows=W),
+        _app(64, "torus"), _cfg(0.02), chunk=16)
+    carry = jax.tree.map(lambda x: x[None], eng._init_carry(0))
+    text = eng._get_runner().lower(carry).as_text()
+    snap_type = "tensor<{}xf32>".format(
+        "x".join(map(str, carry["snap"].shape)))
+    assert snap_type in text
+    assert snap_type not in _scatter_result_types(text)
