@@ -4,8 +4,11 @@ after the timed run against the plain reference's, after as many windows.
 Every number compared is exact, so every limit is 0:
 
   procs_differ          processes whose clock, step count, done flag,
-                        message counters, halo, snapshots or colouring
-                        state differ in any bit from the reference's
+                        message counters, halo, snapshots, colouring
+                        state or barrier state (waiting flag, releases
+                        seen, last release time, saved costs; all zero
+                        where the mode has no barrier) differ in any bit
+                        from the reference's
   digest_fields_differ  fields of the assembled result's digest (updates
                         per process, sent, dropped, quality, every QoS
                         report) that differ from the reference's; a
@@ -24,7 +27,8 @@ import numpy as np
 LIMITS = {"procs_differ": 0, "digest_fields_differ": 0,
           "conservation_gap": 0, "windows_gap": 0}
 PROCESS_FIELDS = ("t", "steps", "done", "c_att", "c_ok", "c_drop", "c_msgs",
-                  "c_laden", "c_touch", "snap_idx", "snap", "halo")
+                  "c_laden", "c_touch", "snap_idx", "snap", "halo",
+                  "waiting", "barrier_seq", "last_release", "pending")
 METRICS = ("simstep_period", "simstep_latency", "walltime_latency",
            "delivery_failure_rate", "delivery_clumpiness")
 

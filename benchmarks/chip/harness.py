@@ -102,6 +102,15 @@ def duration(cell: Cell) -> float:
     return cell.traffic["horizon_steps"] * cell.config["timing"]["base_compute"]
 
 
+def mode_settings(cell: Cell) -> dict:
+    """The traffic's settings of its release mode (the barrier's cost, for
+    one), by the keys the reference declares for that mode: the program
+    and the reference take the same numbers."""
+    import reference
+    tr = cell.traffic
+    return {k: tr[k] for k in reference.MODES[tr["mode"]]}
+
+
 def sim_config(cell: Cell, seed: int):
     from repro.core.modes import AsyncMode
     from repro.runtime.simulator import SimConfig
@@ -111,7 +120,7 @@ def sim_config(cell: Cell, seed: int):
                      snapshot_warmup=dur / tr["snapshot_warmup_div"],
                      snapshot_interval=dur / tr["snapshot_interval_div"],
                      buffer_capacity=c["buffer_capacity"], seed=seed,
-                     **c["timing"])
+                     **c["timing"], **mode_settings(cell))
 
 
 def swarm(cell: Cell):
@@ -123,13 +132,15 @@ def swarm(cell: Cell):
         n=c["processes"], topology=c["topology"],
         app=reference.module("app", c["app"]).App.from_config(c),
         capacity=c["buffer_capacity"], max_pops=c["max_pops"],
-        comm=tr["mode"] != "NO_COMM",
+        mode=tr["mode"],
         duration=dur, snapshot_warmup=dur / tr["snapshot_warmup_div"],
-        snapshot_interval=dur / tr["snapshot_interval_div"], **tm)
+        snapshot_interval=dur / tr["snapshot_interval_div"], **tm,
+        **mode_settings(cell))
 
 
 def check_supported(cell: Cell):
-    """The reference has the cell's topology and app, and its mode."""
+    """The reference has the cell's topology and app modules and declares
+    its mode and faults, and the traffic states what the mode takes."""
     import reference
     c, tr = cell.config, cell.traffic
     try:
@@ -137,9 +148,16 @@ def check_supported(cell: Cell):
         reference.module("app", c["app"])
     except FileNotFoundError as e:
         raise BenchError(str(e)) from None
-    if tr["mode"] not in ("BEST_EFFORT", "NO_COMM") or tr["faults"] != "none":
-        raise BenchError("the reference covers best-effort and no-comm "
-                         "modes without faults")
+    if tr["mode"] not in reference.MODES:
+        raise BenchError(f"the reference has no mode {tr['mode']!r}; it "
+                         f"implements {sorted(reference.MODES)}")
+    if tr["faults"] not in reference.FAULTS:
+        raise BenchError(f"the reference draws no faults {tr['faults']!r}; "
+                         f"it implements {list(reference.FAULTS)}")
+    missing = [k for k in reference.MODES[tr["mode"]] if k not in tr]
+    if missing:
+        raise BenchError(f"mode {tr['mode']!r} takes {missing} from the "
+                         f"traffic file, which does not state them")
 
 
 # ---------------------------------------------------------------------------

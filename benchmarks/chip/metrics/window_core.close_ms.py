@@ -1,6 +1,7 @@
 """Device self milliseconds a window spends closing, less the snapshot
-scatter nested in it (``window.close``: the step-factor draw, termination,
-barriers and the clock advance), from the ops' named scope."""
+select nested in it (``window.close``: the step-factor draw, termination,
+the barrier release's reductions and the clock advance), from the ops'
+named scope."""
 
 from program_spans import phase_ms_per_window
 

@@ -1,6 +1,6 @@
 """Device milliseconds a window spends in the chunk program outside the
 Pallas kernels: the window core's XLA work (gathers, app step, staging,
-snapshot scatter, clock advance)."""
+the snapshot select, barrier release and clock advance)."""
 
 KERNELS = ("_window_kernel", "_commit_kernel")
 

@@ -1,8 +1,7 @@
 """Device self milliseconds a window spends in the QoS snapshot's ops
 under ``window.snapshot`` (inside ``window.close``), from the ops' named
-scope. The loop-level write of the carried snapshot buffer, which XLA
-names after the scan's ``while``, is not under it: ``window_core.loop_ms``
-reads it."""
+scope: the snapshot row and its write into the carried buffer, a masked
+select over the buffer."""
 
 from program_spans import phase_ms_per_window
 

@@ -16,6 +16,15 @@ program. One window, for every process at once:
   close    snapshot, horizon, and the clock advance: the compute time,
            drawn for (process, step) and times ``stall_factor`` on a hashed
            1% of steps, plus per-message and per-pull costs
+  release  under barrier-every-update release only: every process that
+           stepped and is not done waits, keeping its per-message and
+           pull costs; once every process waits or is done, all waiting
+           processes are released together at the latest waiting clock
+           plus the barrier's cost, and each takes its next step from there
+
+A process is active in a window when it is neither done nor waiting.
+``MODES`` and ``FAULTS`` declare what this reference implements; a cell
+whose traffic asks for anything else has no reference and is refused.
 
 The topology and the app are modules of their own, found by the names in
 the configuration: ``reference_topology/<name>.py`` gives each duct's
@@ -34,6 +43,7 @@ from __future__ import annotations
 
 import dataclasses
 import importlib.util
+import math
 from functools import lru_cache, partial
 from pathlib import Path
 
@@ -42,6 +52,13 @@ import jax.numpy as jnp
 import numpy as np
 
 HERE = Path(__file__).resolve().parent
+#: the release modes this reference implements, each with the traffic keys
+#: it takes: the free-running modes take none, barrier-every-update release
+#: takes the barrier's cost, ``barrier_base + barrier_per_log2 * log2 n``
+MODES = {"BEST_EFFORT": (), "NO_COMM": (),
+         "BARRIER_EVERY_STEP": ("barrier_base", "barrier_per_log2")}
+#: the faults it draws: none yet
+FAULTS = ("none",)
 #: outgoing row a sender offers to a receiver's halo slot j (n<->s, w<->e)
 OPPOSITE = (1, 0, 3, 2)
 #: hash stream tags: step time, stall, latency and the app's draws
@@ -108,7 +125,7 @@ class Swarm:
     app: object
     capacity: int
     max_pops: int
-    comm: bool
+    mode: str
     base_compute: float
     per_message_cost: float
     per_pull_cost: float
@@ -120,6 +137,25 @@ class Swarm:
     duration: float
     snapshot_warmup: float
     snapshot_interval: float
+    barrier_base: float = 0.0
+    barrier_per_log2: float = 0.0
+
+    @property
+    def comm(self):
+        return self.mode != "NO_COMM"
+
+    @property
+    def barriered(self):
+        return self.mode == "BARRIER_EVERY_STEP"
+
+    @property
+    def barrier_cost(self):
+        """The release's cost in float64, rounded once to float32; nothing
+        to wait for where there is one process."""
+        if self.n <= 1:
+            return np.float32(0.0)
+        return np.float32(self.barrier_base
+                          + self.barrier_per_log2 * math.log2(self.n))
 
     @property
     def L(self):
@@ -151,8 +187,11 @@ def init_state(sw: Swarm, tabs, seed: int):
     t0 = np.float32(sw.base_compute) * step_factor(sw, seed, pids,
                                                    jnp.zeros(n, jnp.int32))
     zi = lambda: jnp.zeros(n, jnp.int32)
+    zf = lambda: jnp.zeros(n, jnp.float32)
     return dict(
         k=jnp.zeros((), jnp.int32), t=t0, steps=zi(), done=jnp.zeros(n, bool),
+        waiting=jnp.zeros(n, bool), barrier_seq=zi(), last_release=zf(),
+        pending=zf(),
         c_att=zi(), c_ok=zi(), c_drop=zi(), c_msgs=zi(), c_laden=zi(),
         c_touch=zi(), app=app, halo=halo,
         snap=jnp.zeros((n, sw.slots, 8), jnp.float32), snap_idx=zi(),
@@ -177,7 +216,7 @@ def window(sw: Swarm, tabs, seed, s, fault=None):
     D = tabs["eid"].shape[1]
     slots = jnp.arange(C, dtype=jnp.int32)
     pids = jnp.arange(n, dtype=jnp.int32)
-    t, active = s["t"], ~s["done"]
+    t, active = s["t"], ~s["done"] & ~s["waiting"]
     s = dict(s)
     drained_r = jnp.zeros(n, jnp.int32)
 
@@ -258,15 +297,37 @@ def window(sw: Swarm, tabs, seed, s, fault=None):
     s["snap"] = jnp.where(into[..., None], row[:, None, :], s["snap"])
     s["snap_idx"] = idx + due
     ends = active & (t >= np.float32(sw.duration))
-    s["done"] = s["done"] | ends
+    done = s["done"] | ends
     if fault == "no_stall":
         f = lognormal(sw.jitter_sigma, seed, STREAM_STEP, pids, steps)
     else:
         f = step_factor(sw, seed, pids, steps)
-    s["t"] = jnp.where(active & ~ends,
-                       t + np.float32(sw.base_compute) * f + pending, t)
+    d_next = np.float32(sw.base_compute) * f
+    if sw.barriered:
+        s.update(release(sw, s, t, done, active & ~ends, pending, d_next))
+    else:
+        s.update(done=done, t=jnp.where(active & ~ends, t + d_next + pending,
+                                        t))
     s["k"] = s["k"] + 1
     return s
+
+
+def release(sw: Swarm, s, t, done, stepped, pending, d_next):
+    """Barrier-every-update release after a window's step: the waiting,
+    clock, done and barrier fields of the state."""
+    waiting = s["waiting"] | stepped
+    saved = jnp.where(stepped, pending, s["pending"])
+    ready = jnp.all(waiting | done) & jnp.any(waiting)
+    at = jnp.max(jnp.where(waiting, t, -jnp.inf)) + sw.barrier_cost
+    out = ready & waiting
+    horizon = np.float32(sw.duration)
+    past = at >= horizon
+    return dict(
+        waiting=waiting & ~ready, pending=saved,
+        t=jnp.where(out, jnp.where(past, horizon, at + d_next + saved), t),
+        done=done | (out & past),
+        last_release=jnp.where(out, at, s["last_release"]),
+        barrier_seq=s["barrier_seq"] + out)
 
 
 def tables(sw: Swarm):

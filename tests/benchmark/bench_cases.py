@@ -15,7 +15,7 @@ import harness  # noqa: E402
 
 #: small shapes for each cell: (processes, simels per process)
 SMALL = {"gc1-be": (64, 1), "gc1-nocomm": (64, 1), "gc1-be-ss8": (64, 1),
-         "gc2048-be": (64, 8)}
+         "gc2048-be": (64, 8), "gc1-barrier": (64, 1)}
 
 
 def small_cell(workload: str, processes=None, **traffic):

@@ -16,7 +16,8 @@ from control import control  # noqa: E402
 
 @pytest.mark.parametrize("workload,fault", [
     ("gc1-be", "no_latency"), ("gc2048-be", "no_latency"),
-    ("gc1-be-ss8", "no_latency"), ("gc1-nocomm", "no_stall")])
+    ("gc1-be-ss8", "no_latency"), ("gc1-nocomm", "no_stall"),
+    ("gc1-barrier", "no_latency")])
 @pytest.mark.parametrize("seed", [5, 2 ** 31 + 9, 4_000_000_001])
 def test_control_is_not_correct(workload, fault, seed):
     compared = control(small_cell(workload), harness.seed32(seed), 96,
@@ -59,12 +60,23 @@ def _colour_altered(step):
     return run
 
 
-@pytest.mark.parametrize("fault", [_unchanged, _half_left_out,
-                                   _colour_altered])
-def test_broken_timed_path_is_not_correct(fault, monkeypatch):
+def _run_broken(workload, fault, monkeypatch):
     real = harness.compile_chunk
     monkeypatch.setattr(harness, "compile_chunk",
                         lambda engine, carry: fault(real(engine, carry)))
-    run = harness.run_cell(small_cell("gc1-be"), 21, 0.1, None,
-                           jax.devices(), time.perf_counter())
+    return harness.run_cell(small_cell(workload), 21, 0.1, None,
+                            jax.devices(), time.perf_counter())
+
+
+@pytest.mark.parametrize("fault", [_unchanged, _half_left_out,
+                                   _colour_altered])
+def test_broken_timed_path_is_not_correct(fault, monkeypatch):
+    run = _run_broken("gc1-be", fault, monkeypatch)
+    assert not run.correct, run.compared
+
+
+@pytest.mark.parametrize("fault", [_unchanged, _half_left_out,
+                                   _colour_altered])
+def test_broken_barrier_path_is_not_correct(fault, monkeypatch):
+    run = _run_broken("gc1-barrier", fault, monkeypatch)
     assert not run.correct, run.compared

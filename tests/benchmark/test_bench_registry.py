@@ -1,5 +1,6 @@
 """BENCHMARK.json against the benchmark's contract, and every cell and
 metric found by name from files of its own."""
+import dataclasses
 import json
 import os
 import re
@@ -8,6 +9,8 @@ import shutil
 import pytest
 
 from bench_cases import CHIP, ROOT, harness
+
+import reference  # noqa: E402  (on the path once bench_cases is imported)
 
 BENCH = harness.load_benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -52,7 +55,7 @@ def test_every_cell_resolves_to_its_files(workload):
         {w["name"]: w for w in BENCH["workloads"]}[workload]["config"]]
     assert cell.config["name"] == entry["name"]
     assert sorted(cell.config["reduced"]) == sorted(entry["reduced"])
-    assert cell.traffic["mode"] in ("BEST_EFFORT", "NO_COMM")
+    assert cell.traffic["mode"] in reference.MODES
     harness.check_supported(cell)
     assert [m["name"] for m in cell.end_to_end] == ["updates_per_s",
                                                     "setup_s"]
@@ -114,3 +117,40 @@ def test_unknown_workload_is_refused():
 def test_seed_folds_into_int32():
     assert harness.seed32(2 ** 31 + 5) == 5
     assert 0 <= harness.seed32(4_000_000_007) < 2 ** 31
+
+
+@pytest.mark.parametrize("traffic,named", [
+    ({"mode": "ROLLING_BARRIER"}, "ROLLING_BARRIER"),
+    ({"mode": "FIXED_BARRIER"}, "FIXED_BARRIER"),
+    ({"faults": "lossy"}, "lossy"),
+    ({"faults": "crash"}, "crash")])
+def test_check_supported_refuses_what_the_reference_lacks(traffic, named):
+    """A mode or fault the reference does not declare is refused, in the
+    reference's words: the error names it and what the reference has."""
+    cell = harness.resolve(BENCH, "gc1-be")
+    cell = dataclasses.replace(cell, traffic=dict(cell.traffic, **traffic))
+    with pytest.raises(harness.BenchError, match=named) as e:
+        harness.check_supported(cell)
+    assert "BEST_EFFORT" in str(e.value) or "none" in str(e.value)
+
+
+def test_check_supported_refuses_a_barrier_without_its_cost():
+    cell = harness.resolve(BENCH, "gc1-barrier")
+    traffic = {k: v for k, v in cell.traffic.items() if k != "barrier_base"}
+    with pytest.raises(harness.BenchError, match="barrier_base"):
+        harness.check_supported(dataclasses.replace(cell, traffic=traffic))
+
+
+def test_check_supported_refuses_a_missing_reference_module():
+    cell = harness.resolve(BENCH, "gc1-be")
+    cell = dataclasses.replace(cell, config=dict(cell.config,
+                                                 topology="kronecker"))
+    with pytest.raises(harness.BenchError, match="kronecker"):
+        harness.check_supported(cell)
+
+
+def test_program_and_reference_take_the_traffic_barrier_cost():
+    cell = harness.resolve(BENCH, "gc1-barrier")
+    cfg, sw = harness.sim_config(cell, 1), harness.swarm(cell)
+    for k in ("barrier_base", "barrier_per_log2"):
+        assert getattr(cfg, k) == getattr(sw, k) == cell.traffic[k]
