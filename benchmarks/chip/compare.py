@@ -4,13 +4,16 @@ after the timed run against the plain reference's, after as many windows.
 Every number compared is exact, so every limit is 0:
 
   procs_differ          processes whose clock, step count, done flag,
-                        message counters, halo, snapshots, colouring
-                        state or barrier state (waiting flag, releases
-                        seen, last release time, saved costs; all zero
-                        where the mode has no barrier) differ in any bit
-                        from the reference's
+                        message counters (sends lost to a lossy or
+                        flapping link among them: zero where the
+                        program's carry does not count them), halo,
+                        snapshots, colouring state or barrier state
+                        (waiting flag, releases seen, last release time,
+                        saved costs; all zero where the mode has no
+                        barrier) differ in any bit from the reference's
   digest_fields_differ  fields of the assembled result's digest (updates
-                        per process, sent, dropped, quality, every QoS
+                        per process, sent, dropped, dropped to loss and
+                        to dead destinations, quality, every QoS
                         report) that differ from the reference's; a
                         report needs two snapshots inside the run, which
                         the snapshot rule of today's traffic never gives
@@ -26,8 +29,8 @@ import numpy as np
 
 LIMITS = {"procs_differ": 0, "digest_fields_differ": 0,
           "conservation_gap": 0, "windows_gap": 0}
-PROCESS_FIELDS = ("t", "steps", "done", "c_att", "c_ok", "c_drop", "c_msgs",
-                  "c_laden", "c_touch", "snap_idx", "snap", "halo",
+PROCESS_FIELDS = ("t", "steps", "done", "c_att", "c_ok", "c_drop", "c_loss",
+                  "c_msgs", "c_laden", "c_touch", "snap_idx", "snap", "halo",
                   "waiting", "barrier_seq", "last_release", "pending")
 METRICS = ("simstep_period", "simstep_latency", "walltime_latency",
            "delivery_failure_rate", "delivery_clumpiness")
@@ -46,7 +49,10 @@ def _rows_differ(a, b):
 
 
 def program_view(carry):
-    """The program's per-process fields of replicate 0 of a fetched carry."""
+    """The program's per-process fields of replicate 0 of a fetched carry;
+    a program that keeps no loss counter has lost nothing."""
+    carry = dict(carry)
+    carry.setdefault("c_loss", np.zeros_like(carry["c_drop"]))
     out = {k: np.asarray(carry[k][0]) for k in PROCESS_FIELDS}
     out["app"] = {k: np.asarray(v[0]) for k, v in carry["app"].items()}
     out["in_duct"] = np.asarray(carry["q_size"][0])
@@ -86,7 +92,8 @@ def reference_digest(ref, quality, comm):
         "updates": [int(u) for u in ref["steps"]],
         "sent": int(np.sum(ref["c_att"], dtype=np.int64)),
         "dropped": int(np.sum(ref["c_drop"], dtype=np.int64)),
-        "dropped_loss": 0, "dropped_dead": 0,
+        "dropped_loss": int(np.sum(ref["c_loss"], dtype=np.int64)),
+        "dropped_dead": 0,
         "quality": float(quality),
         "qos": reports(ref["snap"], ref["snap_idx"], 4, comm),
     }

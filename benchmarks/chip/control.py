@@ -9,9 +9,11 @@ reference by the benchmark's own comparison. It must come out not correct.
 The broken guarantee is the latency (``--fault no_latency``: a message is
 available as soon as it is sent) where the cell communicates, and the
 stalls of the timing model (``--fault no_stall``: no step ever stalls)
-where it does not. Every number compared and its limit is printed per
-seed, and a JSON line closes the output. The benchmark's runs do not run
-this.
+where it does not. Where the traffic faults a host's links, the link
+fault can be left out instead (``--fault no_link_fault``: the clique keeps
+the timing's latency and loses no send). Every number compared and its
+limit is printed per seed, and a JSON line closes the output. The
+benchmark's runs do not run this.
 """
 import argparse
 import json
@@ -23,7 +25,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import harness  # noqa: E402
 
-FAULTS = ("no_latency", "no_stall")
+FAULTS = ("no_latency", "no_stall", "no_link_fault")
 
 
 def reference_view(state):

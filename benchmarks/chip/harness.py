@@ -7,12 +7,14 @@ are found by name, so a new cell or metric is a new file and a new entry,
 with no edit to this code.
 
 The timed path is the program's own: ``make_engine`` builds the engine,
-``JaxEngine._init_carry`` the carry on the device, and the window drives
-the compiled chunk (``JaxEngine._get_runner``) the way ``run_replicates``
-does: the carry is donated, and the host reads the previous chunk's
-``done`` reduction while the next chunk runs. The window dispatches
-chunks until ``--seconds`` have passed and ends with the user's result,
-``jax.device_get`` of the carry and ``JaxEngine._assemble``.
+with the traffic's fault as the program's constructor of that kind makes
+it, ``JaxEngine._init_carry`` the carry on the device, and the window
+drives the compiled chunk (``JaxEngine._get_runner``) the way
+``run_replicates`` does: the carry is donated, and the host reads the
+previous chunk's ``done`` reduction while the next chunk runs. The window
+dispatches chunks until ``--seconds`` have passed and ends with the
+user's result, ``jax.device_get`` of the carry and
+``JaxEngine._assemble``.
 """
 from __future__ import annotations
 
@@ -111,6 +113,36 @@ def mode_settings(cell: Cell) -> dict:
     return {k: tr[k] for k in reference.MODES[tr["mode"]]}
 
 
+def fault_settings(cell: Cell) -> dict:
+    """The traffic's settings of its fault (the host, the factors), by the
+    keys the reference declares for that kind."""
+    import reference
+    tr = cell.traffic
+    return {k: tr[k] for k in reference.FAULTS[tr["faults"]]}
+
+
+def topology(cell: Cell):
+    """The program's topology of the configuration, its processes placed on
+    hosts by ``procs_per_node`` where the configuration states it."""
+    from repro.runtime.topologies import make_topology
+    c = cell.config
+    hosts = ({"procs_per_node": c["procs_per_node"]}
+             if "procs_per_node" in c else {})
+    return make_topology(c["topology"], c["processes"], **hosts)
+
+
+def fault_model(cell: Cell, topo):
+    """The traffic's fault as the program's own constructor of that kind
+    builds it on ``topo``; None where the traffic has no fault."""
+    kind = cell.traffic["faults"]
+    if kind == "none":
+        return None
+    from repro.runtime import faults
+    settings = fault_settings(cell)
+    return getattr(faults, kind)(topo, settings.pop("fault_host"),
+                                 **settings)
+
+
 def sim_config(cell: Cell, seed: int):
     from repro.core.modes import AsyncMode
     from repro.runtime.simulator import SimConfig
@@ -135,12 +167,15 @@ def swarm(cell: Cell):
         mode=tr["mode"],
         duration=dur, snapshot_warmup=dur / tr["snapshot_warmup_div"],
         snapshot_interval=dur / tr["snapshot_interval_div"], **tm,
-        **mode_settings(cell))
+        **mode_settings(cell), faults=tr["faults"],
+        procs_per_node=c.get("procs_per_node"), **fault_settings(cell))
 
 
 def check_supported(cell: Cell):
     """The reference has the cell's topology and app modules and declares
-    its mode and faults, and the traffic states what the mode takes."""
+    its mode and faults, the traffic states what the mode and the faults
+    take, and the configuration places processes on hosts where a fault
+    strikes one."""
     import reference
     c, tr = cell.config, cell.traffic
     try:
@@ -154,10 +189,17 @@ def check_supported(cell: Cell):
     if tr["faults"] not in reference.FAULTS:
         raise BenchError(f"the reference draws no faults {tr['faults']!r}; "
                          f"it implements {list(reference.FAULTS)}")
-    missing = [k for k in reference.MODES[tr["mode"]] if k not in tr]
-    if missing:
-        raise BenchError(f"mode {tr['mode']!r} takes {missing} from the "
-                         f"traffic file, which does not state them")
+    takes = {f"mode {tr['mode']!r}": reference.MODES[tr["mode"]],
+             f"fault {tr['faults']!r}": reference.FAULTS[tr["faults"]]}
+    for what, keys in takes.items():
+        missing = [k for k in keys if k not in tr]
+        if missing:
+            raise BenchError(f"{what} takes {missing} from the traffic "
+                             f"file, which does not state them")
+    if "fault_host" in reference.FAULTS[tr["faults"]] \
+            and "procs_per_node" not in c:
+        raise BenchError(f"fault {tr['faults']!r} strikes a host; the "
+                         f"configuration does not state procs_per_node")
 
 
 # ---------------------------------------------------------------------------
@@ -180,19 +222,18 @@ def build(cell: Cell, seed: int):
     from repro.apps.graphcolor import GraphColorApp, GraphColorConfig
     from repro.runtime.config import RunConfig
     from repro.runtime.engine import make_engine
-    from repro.runtime.topologies import make_topology
     c, tr = cell.config, cell.traffic
-    n = c["processes"]
+    topo = topology(cell)
     app = GraphColorApp(
-        GraphColorConfig(n_processes=n,
+        GraphColorConfig(n_processes=c["processes"],
                          nodes_per_process=c["simels_per_process"],
                          n_colors=c["n_colors"], b=c["b"], seed=seed),
-        topology=make_topology(c["topology"], n))
+        topology=topo)
     eng = make_engine(
         RunConfig(engine="jax", scheduler=tr["scheduler"],
                   superstep_windows=tr["superstep_windows"]),
-        app, sim_config(cell, seed), max_pops=c["max_pops"],
-        chunk=tr["chunk"])
+        app, sim_config(cell, seed), fault_model(cell, topo),
+        max_pops=c["max_pops"], chunk=tr["chunk"])
     carry = jax.tree.map(lambda x: x[None], eng._init_carry(seed))
     return eng, jax.block_until_ready(carry)
 

@@ -11,11 +11,14 @@ program. One window, for every process at once:
            halo slot the duct feeds
   compute  the app's step of every active process against its halos
   send     each active process pushes one message into each out-duct; a
-           full duct drops it; an accepted one becomes available after a
-           latency drawn for (edge, sender step) on the sender's clock
+           send that a lossy or flapping link kills is attempted and
+           dropped, and counted lost; a full duct drops it; an accepted
+           one becomes available after its duct's latency, times a factor
+           drawn for (edge, sender step), on the sender's clock
   close    snapshot, horizon, and the clock advance: the compute time,
-           drawn for (process, step) and times ``stall_factor`` on a hashed
-           1% of steps, plus per-message and per-pull costs
+           drawn for (process, step), times ``stall_factor`` on a hashed
+           1% of steps and times the process's compute factor, plus
+           per-message and per-pull costs
   release  under barrier-every-update release only: every process that
            stepped and is not done waits, keeping its per-message and
            pull costs; once every process waits or is done, all waiting
@@ -25,6 +28,19 @@ program. One window, for every process at once:
 A process is active in a window when it is neither done nor waiting.
 ``MODES`` and ``FAULTS`` declare what this reference implements; a cell
 whose traffic asks for anything else has no reference and is refused.
+
+Faults strike one host, the processes ``p // procs_per_node == fault_host``,
+and its clique, every duct with either end on it (the paper's apparently
+faulty node and its degraded links):
+
+  faulty_host    the host's compute times ``compute_factor``, its clique's
+                 latency base ``base_latency * link_factor``, taken in
+                 float64 and rounded once to float32
+  lossy_host     a clique send is killed where the draw for (edge, sender
+                 step) falls below ``loss_prob``
+  flapping_host  a clique send is killed where the draw for (edge,
+                 ``floor(t_sender / flap_period)``) falls below
+                 ``down_frac``: the link is down for that time bucket
 
 The topology and the app are modules of their own, found by the names in
 the configuration: ``reference_topology/<name>.py`` gives each duct's
@@ -46,6 +62,7 @@ import importlib.util
 import math
 from functools import lru_cache, partial
 from pathlib import Path
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,12 +74,17 @@ HERE = Path(__file__).resolve().parent
 #: takes the barrier's cost, ``barrier_base + barrier_per_log2 * log2 n``
 MODES = {"BEST_EFFORT": (), "NO_COMM": (),
          "BARRIER_EVERY_STEP": ("barrier_base", "barrier_per_log2")}
-#: the faults it draws: none yet
-FAULTS = ("none",)
+#: the faults it draws, each with the traffic keys it takes; a kind is
+#: named after the program's constructor of its fault model
+FAULTS = {"none": (),
+          "faulty_host": ("fault_host", "compute_factor", "link_factor"),
+          "lossy_host": ("fault_host", "loss_prob"),
+          "flapping_host": ("fault_host", "down_frac", "flap_period")}
 #: outgoing row a sender offers to a receiver's halo slot j (n<->s, w<->e)
 OPPOSITE = (1, 0, 3, 2)
-#: hash stream tags: step time, stall, latency and the app's draws
+#: hash stream tags: step time, stall, latency, the app's, loss and flap
 STREAM_STEP, STREAM_STALL, STREAM_LAT, STREAM_APP = 1, 2, 3, 4
+STREAM_LOSS, STREAM_FLAP = 6, 7
 _GOLDEN = np.uint32(0x9E3779B9)
 
 
@@ -139,6 +161,16 @@ class Swarm:
     snapshot_interval: float
     barrier_base: float = 0.0
     barrier_per_log2: float = 0.0
+    #: the fault and its settings; the neutral values leave every process
+    #: and duct as the timing states it
+    faults: str = "none"
+    procs_per_node: Optional[int] = None
+    fault_host: int = 0
+    compute_factor: float = 1.0
+    link_factor: float = 1.0
+    loss_prob: float = 0.0
+    down_frac: float = 0.0
+    flap_period: float = 1.0
 
     @property
     def comm(self):
@@ -168,15 +200,16 @@ class Swarm:
                           / self.snapshot_interval) + 3)
 
 
-def step_factor(sw: Swarm, seed, pids, steps):
+def step_factor(sw: Swarm, seed, pids, steps, cf):
     """Compute-time factor of each process's step ``steps``: the lognormal
-    jitter, times ``stall_factor`` where the stall draw hits."""
+    jitter, times ``stall_factor`` where the stall draw hits, times the
+    process's compute factor ``cf``."""
     f = lognormal(sw.jitter_sigma, seed, STREAM_STEP, pids, steps)
     if sw.stall_prob > 0:
         u = hash_uniform(seed, STREAM_STALL, pids, steps)
         f = jnp.where(u < np.float32(sw.stall_prob),
                       f * np.float32(sw.stall_factor), f)
-    return f
+    return f * cf
 
 
 def init_state(sw: Swarm, tabs, seed: int):
@@ -184,16 +217,16 @@ def init_state(sw: Swarm, tabs, seed: int):
     n, C, L, D = sw.n, sw.capacity, sw.L, tabs["eid"].shape[1]
     app, halo = sw.app.init(seed, n)
     pids = jnp.arange(n, dtype=jnp.int32)
-    t0 = np.float32(sw.base_compute) * step_factor(sw, seed, pids,
-                                                   jnp.zeros(n, jnp.int32))
+    t0 = np.float32(sw.base_compute) * step_factor(
+        sw, seed, pids, jnp.zeros(n, jnp.int32), tabs["cf"])
     zi = lambda: jnp.zeros(n, jnp.int32)
     zf = lambda: jnp.zeros(n, jnp.float32)
     return dict(
         k=jnp.zeros((), jnp.int32), t=t0, steps=zi(), done=jnp.zeros(n, bool),
         waiting=jnp.zeros(n, bool), barrier_seq=zi(), last_release=zf(),
         pending=zf(),
-        c_att=zi(), c_ok=zi(), c_drop=zi(), c_msgs=zi(), c_laden=zi(),
-        c_touch=zi(), app=app, halo=halo,
+        c_att=zi(), c_ok=zi(), c_drop=zi(), c_loss=zi(), c_msgs=zi(),
+        c_laden=zi(), c_touch=zi(), app=app, halo=halo,
         snap=jnp.zeros((n, sw.slots, 8), jnp.float32), snap_idx=zi(),
         q_avail=jnp.full((n, D, C), jnp.inf, jnp.float32),
         q_touch=jnp.zeros((n, D, C), jnp.int32),
@@ -204,10 +237,11 @@ def init_state(sw: Swarm, tabs, seed: int):
 
 def window(sw: Swarm, tabs, seed, s, fault=None):
     """One lockstep window. ``fault`` names a broken guarantee for the
-    control run: ``"no_latency"`` (messages are available when sent) or
-    ``"no_stall"`` (no step ever stalls).
+    control run: ``"no_latency"`` (messages are available when sent),
+    ``"no_stall"`` (no step ever stalls) or ``"no_link_fault"`` (the
+    clique's links keep the timing's latency and lose nothing).
 
-    ``tabs`` are the topology's tables; ``tabs["eid"][d, j]`` is the
+    ``tabs`` are the tables of ``tables``; ``tabs["eid"][d, j]`` is the
     canonical edge id of duct ``(d, j)``."""
     topo = module("topology", sw.topology)
     from_sender = partial(topo.from_sender, tabs)
@@ -258,15 +292,28 @@ def window(sw: Swarm, tabs, seed, s, fault=None):
         # duct (d, j) carries its sender's outgoing row opposite to slot j
         payload = jnp.stack([from_sender(rows[:, OPPOSITE[j]])[:, j]
                              for j in range(D)], axis=1)
-        accept = from_sender(active) & (s["size"] < C)
+        sending = from_sender(active)
+        killed = jnp.zeros((n, D), bool)
+        if fault != "no_link_fault":
+            if sw.loss_prob > 0:
+                killed |= hash_uniform(seed, STREAM_LOSS, tabs["eid"],
+                                       from_sender(steps)) < tabs["loss"]
+            if sw.down_frac > 0:
+                bucket = jnp.floor(from_sender(t) / np.float32(
+                    sw.flap_period)).astype(jnp.int32)
+                killed |= hash_uniform(seed, STREAM_FLAP, tabs["eid"],
+                                       bucket) < tabs["flap"]
+        killed &= sending
+        accept = sending & ~killed & (s["size"] < C)
         tail = (slots == ((s["head"] + s["size"]) % C)[..., None]) \
             & accept[..., None]
         if fault == "no_latency":
             lat = jnp.zeros((n, D), jnp.float32)
         else:
-            lat = np.float32(sw.base_latency) * lognormal(
-                sw.latency_sigma, seed, STREAM_LAT, tabs["eid"],
-                from_sender(steps))
+            base = (np.float32(sw.base_latency) if fault == "no_link_fault"
+                    else tabs["lat"])
+            lat = base * lognormal(sw.latency_sigma, seed, STREAM_LAT,
+                                   tabs["eid"], from_sender(steps))
         s.update(
             q_avail=jnp.where(tail, (from_sender(t) + lat)[..., None],
                               s["q_avail"]),
@@ -280,7 +327,9 @@ def window(sw: Swarm, tabs, seed, s, fault=None):
         ok = of_reverse(accept.astype(jnp.int32)).sum(1)
         att = jnp.where(active, D, 0)
         s.update(c_att=s["c_att"] + att, c_ok=s["c_ok"] + ok,
-                 c_drop=s["c_drop"] + att - ok)
+                 c_drop=s["c_drop"] + att - ok,
+                 c_loss=s["c_loss"] + of_reverse(
+                     killed.astype(jnp.int32)).sum(1))
 
     pending = (drained_r.astype(jnp.float32) * np.float32(sw.per_message_cost)
                + np.float32(D) * np.float32(sw.per_pull_cost))
@@ -299,9 +348,10 @@ def window(sw: Swarm, tabs, seed, s, fault=None):
     ends = active & (t >= np.float32(sw.duration))
     done = s["done"] | ends
     if fault == "no_stall":
-        f = lognormal(sw.jitter_sigma, seed, STREAM_STEP, pids, steps)
+        f = lognormal(sw.jitter_sigma, seed, STREAM_STEP, pids,
+                      steps) * tabs["cf"]
     else:
-        f = step_factor(sw, seed, pids, steps)
+        f = step_factor(sw, seed, pids, steps, tabs["cf"])
     d_next = np.float32(sw.base_compute) * f
     if sw.barriered:
         s.update(release(sw, s, t, done, active & ~ends, pending, d_next))
@@ -331,9 +381,25 @@ def release(sw: Swarm, s, t, done, stepped, pending, d_next):
 
 
 def tables(sw: Swarm):
-    """The topology's duct tables on the default device."""
-    return {k: jnp.asarray(v, jnp.int32)
-            for k, v in module("topology", sw.topology).tables(sw.n).items()}
+    """The topology's duct tables and the fault's, on the default device:
+    ``cf`` each process's compute factor, ``lat`` each duct's latency
+    base, ``loss`` and ``flap`` its loss and down fractions."""
+    topo = module("topology", sw.topology)
+    tabs = topo.tables(sw.n)
+    on = np.zeros(sw.n, bool)
+    if sw.faults != "none":
+        on = topo.hosts(sw.n, sw.procs_per_node) == sw.fault_host
+    hit = topo.clique(tabs, on)
+    faulted = lambda x, normal: np.where(hit, x, normal).astype(np.float32)
+    out = {k: jnp.asarray(v, jnp.int32) for k, v in tabs.items()}
+    out.update(
+        cf=jnp.asarray(np.where(on, sw.compute_factor, 1.0)
+                       .astype(np.float32)),
+        lat=jnp.asarray(faulted(sw.base_latency * sw.link_factor,
+                                sw.base_latency)),
+        loss=jnp.asarray(faulted(sw.loss_prob, 0.0)),
+        flap=jnp.asarray(faulted(sw.down_frac, 0.0)))
+    return out
 
 
 @partial(jax.jit, static_argnums=(0, 3, 4), donate_argnums=(2,))

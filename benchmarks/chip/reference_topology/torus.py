@@ -8,6 +8,9 @@ order: ``4 s + `` the rank of ``d`` among ``s``'s neighbours.
 
 What a duct needs from its sender comes by rolling the grid toward the
 sender's direction, with no gather.
+
+Processes are placed on hosts in blocks of ``procs_per_node`` consecutive
+numbers; a host's clique is every directed duct with either end on it.
 """
 import math
 
@@ -47,6 +50,18 @@ def tables(n: int) -> dict:
     rank = np.argmax(src[src] == np.arange(n)[:, None, None], axis=2)
     return dict(src=src, rev=4 * src + rank, eid=4 * src + rank,
                 slot_dir=slot_dir, dir_slot=np.argsort(slot_dir, axis=1))
+
+
+def hosts(n: int, procs_per_node: int):
+    """(n,) host of each process: process ``p`` is on host
+    ``p // procs_per_node``."""
+    return np.arange(n) // procs_per_node
+
+
+def clique(tabs, on):
+    """(n, 4) ducts with either end on the host, from ``on``, the (n,)
+    mask of the processes placed on it."""
+    return on[:, None] | on[tabs["src"]]
 
 
 def _toward(x, k):

@@ -13,6 +13,14 @@ for p in (CHIP, os.path.join(ROOT, "src")):
 
 import harness  # noqa: E402
 
+#: each fault kind of the reference, at the program's default settings, on
+#: host 0: the traffic keys a faulted cell's file states
+FAULTED = {
+    "faulty_host": dict(fault_host=0, compute_factor=30.0, link_factor=50.0),
+    "lossy_host": dict(fault_host=0, loss_prob=0.05),
+    "flapping_host": dict(fault_host=0, down_frac=0.5,
+                          flap_period=2.0 ** -12)}
+
 #: small shapes for each cell: (processes, simels per process)
 SMALL = {"gc1-be": (64, 1), "gc1-nocomm": (64, 1), "gc1-be-ss8": (64, 1),
          "gc2048-be": (64, 8), "gc1-barrier": (64, 1)}

@@ -1,14 +1,15 @@
 """The comparison that decides ``correct`` fails what it must: the control
-(the reference with latency broken, in the program's place) and the timed
-path broken underneath the harness, once for each fault a one-chip cell
-can have. The exchange between chips does not exist on one chip."""
+(the reference with latency, or a faulted host's link fault, broken, in the
+program's place) and the timed path broken underneath the harness, once for
+each fault a one-chip cell can have. The exchange between chips does not
+exist on one chip."""
 import time
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from bench_cases import harness, small_cell
+from bench_cases import FAULTED, harness, small_cell
 
 import compare  # noqa: E402  (on the path once bench_cases is imported)
 from control import control  # noqa: E402
@@ -22,6 +23,17 @@ from control import control  # noqa: E402
 def test_control_is_not_correct(workload, fault, seed):
     compared = control(small_cell(workload), harness.seed32(seed), 96,
                        fault)
+    assert not compare.is_correct(compared)
+    assert compared["procs_differ"]["value"] > 0
+
+
+@pytest.mark.parametrize("kind", sorted(FAULTED))
+@pytest.mark.parametrize("seed", [5, 2 ** 31 + 9])
+def test_control_without_the_link_fault_is_not_correct(kind, seed):
+    """The reference with the clique's link fault left out (latency of the
+    timing, no loss, no flap) in the program's place."""
+    cell = small_cell("gc1-be", faults=kind, **FAULTED[kind])
+    compared = control(cell, harness.seed32(seed), 96, "no_link_fault")
     assert not compare.is_correct(compared)
     assert compared["procs_differ"]["value"] > 0
 

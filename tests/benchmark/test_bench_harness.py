@@ -13,7 +13,7 @@ import jax
 import numpy as np
 import pytest
 
-from bench_cases import ROOT, SMALL, harness, small_cell
+from bench_cases import FAULTED, ROOT, SMALL, harness, small_cell
 
 
 def test_loop_counts_updates_and_assembles():
@@ -44,10 +44,15 @@ def test_horizon_inside_the_window_is_an_error():
         harness.measure(engine, step, jax.jit(harness.any_done), carry, 5.0)
 
 
-@pytest.mark.parametrize("workload", sorted(SMALL))
-def test_run_is_correct_against_the_reference(workload, capsys):
-    run = harness.run_cell(small_cell(workload), harness.seed32(2 ** 31 + 77),
-                           0.2, None, jax.devices(), time.perf_counter())
+@pytest.mark.parametrize("workload,traffic", [
+    pytest.param(w, {}, id=w) for w in sorted(SMALL)] + [
+    pytest.param("gc1-be", dict(faults=kind, **settings), id=f"gc1-be-{kind}")
+    for kind, settings in sorted(FAULTED.items())])
+def test_run_is_correct_against_the_reference(workload, traffic, capsys):
+    """Each cell, and the best-effort cell with each faulted host."""
+    run = harness.run_cell(small_cell(workload, **traffic),
+                           harness.seed32(2 ** 31 + 77), 0.2, None,
+                           jax.devices(), time.perf_counter())
     line = json.loads(run.line)
     assert run.correct and line["correct"], run.compared
     assert list(line)[-1] == "compared"

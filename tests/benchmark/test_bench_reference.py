@@ -1,7 +1,7 @@
 """The reference's topology tables and draws agree with the conventions the
 program states: duct slots by ascending neighbour, source-major canonical
 edge ids, and the counter-based lognormal draws; its barrier-every-update
-release equals the program's bit for bit."""
+release and its faulted hosts equal the program's bit for bit."""
 import dataclasses
 
 import jax
@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench_cases import harness, small_cell
+from bench_cases import FAULTED, harness, small_cell
 
 import reference  # noqa: E402
 
@@ -126,3 +126,58 @@ def test_barrier_cost_is_rounded_once_and_nothing_for_one_process():
     assert sw.barrier_cost == np.float32(2e-5 + 1.5e-5 * 6)
     one = dataclasses.replace(sw, n=1)
     assert one.barrier_cost == 0
+
+
+def test_hosts_and_cliques_follow_the_program_placement():
+    from repro.runtime.topologies import make_topology
+    torus = reference.module("topology", "torus")
+    tabs = torus.tables(64)
+    topo = make_topology("torus", 64, procs_per_node=4)
+    host = torus.hosts(64, 4)
+    assert list(host) == list(topo.node_of)
+    for h in (0, 5, 15):
+        pids = set(topo.host_pids(h))
+        want = {(s, d) for p in pids for nb in topo.neighbors[p]
+                for s, d in ((p, nb), (nb, p))}
+        hit = torus.clique(tabs, host == h)
+        got = {(int(tabs["src"][d, j]), d) for d, j in zip(*np.nonzero(hit))}
+        assert got == want
+
+
+@pytest.mark.parametrize("workload", ["gc1-be", "gc2048-be"])
+@pytest.mark.parametrize("seed", [17, 2 ** 31 + 5])
+@pytest.mark.parametrize("kind", sorted(FAULTED))
+def test_faulted_host_equals_the_program_bit_for_bit(kind, workload, seed):
+    """The traffic's fault, built by the program's own constructor on host
+    0 of both configurations, against the reference's draws: every
+    compared number 0, losses counted alike, and the fault acts."""
+    import compare
+    assert "c_loss" in compare.PROCESS_FIELDS
+    cell = small_cell(workload, faults=kind, **FAULTED[kind])
+    harness.check_supported(cell)
+    seed = harness.seed32(seed)
+    sw, ref, compared, failed = _program_and_reference(cell, seed,
+                                                       BARRIER_WINDOWS)
+    assert all(c["value"] == 0 for c in compared.values()), compared
+    assert failed == 0
+    torus = reference.module("topology", "torus")
+    tabs = torus.tables(sw.n)
+    on = torus.hosts(sw.n, 4) == 0
+    # senders with a duct in the clique: the host and its neighbours
+    clique = on | np.isin(np.arange(sw.n), tabs["src"][on])
+    drop, loss = ref["c_drop"], ref["c_loss"]
+    if kind == "faulty_host":
+        # 30x compute: the host's clocks lead; 50x links: its out-ducts
+        # fill before their first message is due, and drop
+        assert ref["t"][on].min() > ref["t"][~on].max()
+        assert drop[on].min() > 0
+        assert drop[on].mean() > 2 * drop[~clique].mean()
+        assert not loss.any()
+    else:
+        assert loss[on].min() > 0 and not loss[~clique].any()
+        assert (drop >= loss).all()
+        if kind == "flapping_host":
+            # down in some time buckets, up in others: the host's sends
+            # are killed and accepted both, about half killed
+            assert (ref["c_ok"][on] > 0).all()
+            assert 0.2 < loss[on].sum() / ref["c_att"][on].sum() < 0.8

@@ -134,6 +134,66 @@ def test_check_supported_refuses_what_the_reference_lacks(traffic, named):
     assert "BEST_EFFORT" in str(e.value) or "none" in str(e.value)
 
 
+@pytest.mark.parametrize("kind,key", [
+    (kind, key) for kind, keys in reference.FAULTS.items() for key in keys])
+def test_check_supported_refuses_a_fault_without_its_settings(kind, key):
+    """A declared fault kind whose traffic leaves out one of its keys is
+    refused, naming the kind and the key."""
+    cell = harness.resolve(BENCH, "gc1-be")
+    traffic = dict(cell.traffic, faults=kind,
+                   **{k: 1 for k in reference.FAULTS[kind]})
+    harness.check_supported(dataclasses.replace(cell, traffic=traffic))
+    del traffic[key]
+    with pytest.raises(harness.BenchError, match=key) as e:
+        harness.check_supported(dataclasses.replace(cell, traffic=traffic))
+    assert kind in str(e.value)
+
+
+def test_check_supported_refuses_a_host_fault_without_hosts():
+    cell = harness.resolve(BENCH, "gc1-be")
+    traffic = dict(cell.traffic, faults="lossy_host", fault_host=0,
+                   loss_prob=0.05)
+    config = {k: v for k, v in cell.config.items() if k != "procs_per_node"}
+    with pytest.raises(harness.BenchError, match="procs_per_node"):
+        harness.check_supported(dataclasses.replace(cell, config=config,
+                                                    traffic=traffic))
+
+
+@pytest.mark.parametrize("kind,settings,fields", [
+    ("faulty_host", dict(fault_host=3, compute_factor=30.0, link_factor=50.0),
+     dict(compute_slowdown=30.0, link_slowdown=50.0)),
+    ("lossy_host", dict(fault_host=3, loss_prob=0.05),
+     dict(link_loss=0.05)),
+    ("flapping_host", dict(fault_host=3, down_frac=0.5, flap_period=2e-4),
+     dict(link_flap=0.5))])
+def test_program_and_reference_take_the_traffic_fault(kind, settings,
+                                                      fields):
+    """The program's fault model comes from its own constructor of the
+    traffic's kind, on host 3 of the configuration's placement; the
+    reference takes the same numbers."""
+    cell = harness.resolve(BENCH, "gc1-be")
+    cell = dataclasses.replace(cell, traffic=dict(cell.traffic, faults=kind,
+                                                  **settings))
+    topo = harness.topology(cell)
+    model = harness.fault_model(cell, topo)
+    host = set(topo.host_pids(3))
+    assert host == {12, 13, 14, 15}
+    for name, value in fields.items():
+        got = getattr(model, name)
+        assert set(got.values()) == {value}
+        if name == "compute_slowdown":
+            assert set(got) == host
+        else:
+            assert all(s in host or d in host for s, d in got)
+    assert model.flap_period == settings.get("flap_period",
+                                             model.flap_period)
+    sw = harness.swarm(cell)
+    assert sw.faults == kind and sw.procs_per_node == 4
+    for k, v in settings.items():
+        assert getattr(sw, k) == v
+    assert harness.fault_model(harness.resolve(BENCH, "gc1-be"), topo) is None
+
+
 def test_check_supported_refuses_a_barrier_without_its_cost():
     cell = harness.resolve(BENCH, "gc1-barrier")
     traffic = {k: v for k, v in cell.traffic.items() if k != "barrier_base"}
